@@ -12,7 +12,8 @@ alone (``python -m repro run`` accepts the embedded spec).
 Failure policy: a raising grid cell must not silently truncate the dump.
 Benchmarks wrap per-cell work in :func:`run_cell`, which records the failing
 cell + exception into the payload's ``errors`` list (written by
-:func:`dump`) and keeps the rest of the grid running; the driver
+:func:`dump`) and keeps the rest of the grid running; :func:`dump` then
+raises, so a module with a failed cell exits non-zero.  The driver
 (benchmarks/run.py) does the same per benchmark module.
 """
 
@@ -44,7 +45,9 @@ def dump(name: str, payload, *, specs=None, seed=None, errors=None) -> None:
     or a list); ``seed``: the driving seed when no spec applies.
     ``errors``: failed-cell records from :func:`run_cell` -- written into the
     document (as ``errors``) so a raising cell leaves a visible trace in the
-    artifact instead of a silently missing row.
+    artifact instead of a silently missing row; any record then raises
+    :class:`CellsFailed` once the document is written, so the module (and
+    ``benchmarks/run.py``) exits non-zero.
     """
     import jax
 
@@ -61,6 +64,14 @@ def dump(name: str, payload, *, specs=None, seed=None, errors=None) -> None:
     if errors is not None:
         doc["errors"] = list(errors)
     (OUT_DIR / f"{name}.json").write_text(json.dumps(doc, indent=1))
+    if errors:
+        raise CellsFailed(f"{name}: {len(errors)} cell(s) failed: "
+                          + "; ".join(f"{e['cell']}: {e['error']}"
+                                      for e in errors))
+
+
+class CellsFailed(RuntimeError):
+    """A benchmark recorded failed cells (see :func:`dump`)."""
 
 
 def append_trajectory(entry: dict) -> None:

@@ -88,4 +88,7 @@ def main(argv: list[str] | None = None) -> None:
 
 
 if __name__ == '__main__':
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     main()

@@ -190,4 +190,7 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     sys.exit(main())

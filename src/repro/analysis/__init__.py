@@ -3,7 +3,7 @@
 Two layers behind one CLI (``python -m repro analyze``):
 
 * :mod:`repro.analysis.lint` -- rule registry + AST lint enforcing the
-  purity / donation / mesh / version-floor invariants on source.
+  purity / donation / mesh / x64 invariants on source.
 * :mod:`repro.analysis.contracts` -- lowers the traced entry points with
   abstract inputs and asserts the scan-fusion / no-callback / donation /
   bucket-cache contracts from the jaxpr and compiled HLO.

@@ -188,7 +188,6 @@ def check_lag_contracts() -> list[ContractResult]:
     """``lag_run_traced`` under ``enable_x64``: same two contracts (the
     in-graph event queue adds sort/cond/top_k -- none may call home)."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.core import compress
     from repro.core.executor import lag_run_traced
@@ -200,7 +199,7 @@ def check_lag_contracts() -> list[ContractResult]:
             dense_reply_bytes=_D * 4)
 
     out = []
-    with enable_x64():
+    with jax.enable_x64(True):
         args = _tiny_lag_args()
         jaxpr = jax.make_jaxpr(entry)(*args)
         scans = top_level_scans(jaxpr)

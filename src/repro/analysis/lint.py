@@ -5,13 +5,9 @@ be enforced by convention only (ROADMAP "standing constraints", docstrings,
 after-the-fact runtime counters).  This module turns them into machine-checked
 contracts over the source AST -- no imports, no tracing, no device:
 
-* ``version-floor``      -- JAX-0.4.37-incompatible spellings
-  (``jax.tree.flatten_with_path``, ``jax.sharding.AxisType``).
 * ``mesh-via-make-mesh`` -- device meshes are built ONLY through
-  :func:`repro.launch.mesh.make_mesh` (the version-safe wrapper); any direct
+  :func:`repro.launch.mesh.make_mesh` (Auto-typed axes); any direct
   ``jax.sharding.Mesh(...)`` / ``jax.make_mesh(...)`` elsewhere is an error.
-* ``pallas-scalar-index``-- bare dynamic scalar indices on Pallas refs
-  (``ref[k]``): 0.4.x interpret mode needs ``pl.ds(k, 1)``.
 * ``traced-host-sync``   -- host synchronization (``.item()``, ``float()``
   on arrays, ``np.asarray``, ``time.*``, Python RNG) inside functions
   *reachable from traced entry points* (``jax.jit`` / ``lax.scan`` /
@@ -298,7 +294,7 @@ TRACE_CONSUMERS = frozenset({
     "jax.lax.scan", "jax.lax.while_loop", "jax.lax.fori_loop",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.pallas.pallas_call",
 })
 
@@ -499,40 +495,6 @@ class ProjectIndex:
 # ---------------------------------------------------------------------------
 
 
-@register_rule("version-floor")
-class VersionFloorRule(Rule):
-    """JAX-0.4.37 floor: spellings that only exist from JAX 0.5."""
-
-    description = ("flags jax.tree.flatten_with_path / jax.sharding.AxisType "
-                   "and friends (ROADMAP: JAX floor is 0.4.37); use "
-                   "jax.tree_util.tree_flatten_with_path and "
-                   "launch/mesh.make_mesh")
-
-    BANNED = {
-        "jax.tree.flatten_with_path":
-            "use jax.tree_util.tree_flatten_with_path (jax.tree spelling "
-            "needs JAX >= 0.5; floor is 0.4.37)",
-        "jax.tree.map_with_path":
-            "use jax.tree_util.tree_map_with_path (needs JAX >= 0.5)",
-        "jax.tree.leaves_with_path":
-            "use jax.tree_util.tree_leaves_with_path (needs JAX >= 0.5)",
-        "jax.sharding.AxisType":
-            "jax.sharding.AxisType needs JAX >= 0.5; build meshes through "
-            "repro.launch.mesh.make_mesh (guarded getattr)",
-    }
-
-    def check(self, module, project):
-        out = []
-        for node in ast.walk(module.tree):
-            if not isinstance(node, ast.Attribute):
-                continue
-            canon = module.canonical(node)
-            if canon in self.BANNED:
-                out.append(module.finding(self.rule_name, node.lineno,
-                                          self.BANNED[canon]))
-        return out
-
-
 @register_rule("mesh-via-make-mesh")
 class MeshRule(Rule):
     """The ROADMAP mesh rule, in code: meshes only via launch/mesh."""
@@ -557,73 +519,8 @@ class MeshRule(Rule):
                 out.append(module.finding(
                     self.rule_name, node.lineno,
                     f"direct {canon}(...) construction; build meshes only "
-                    f"through repro.launch.mesh.make_mesh (version-safe "
-                    f"axis_types handling)"))
-        return out
-
-
-@register_rule("pallas-scalar-index")
-class PallasScalarIndexRule(Rule):
-    """Bare dynamic scalar indices on Pallas refs break 0.4.x interpret."""
-
-    description = ("flags ref[k] / pl.load(ref, (k,)) with a bare dynamic "
-                   "scalar index in Pallas kernels; use pl.ds(k, 1) "
-                   "(JAX 0.4.x interpret-mode contract)")
-
-    _LOAD_STORE = {"load", "store"}
-
-    def _uses_pallas(self, module) -> bool:
-        return any(v.startswith("jax.experimental.pallas")
-                   for v in module.imports.values())
-
-    def _dynamic_elements(self, module, index) -> list[ast.AST]:
-        elems = index.elts if isinstance(index, ast.Tuple) else [index]
-        bad = []
-        for e in elems:
-            if isinstance(e, (ast.Constant, ast.Slice)):
-                continue
-            if isinstance(e, ast.Constant) or (
-                    isinstance(e, ast.UnaryOp)
-                    and isinstance(e.operand, ast.Constant)):
-                continue
-            if isinstance(e, ast.Call):
-                canon = module.canonical(e.func) or ""
-                if canon.endswith((".ds", ".dslice")) or canon == "slice":
-                    continue
-            elif _dotted(e) == "Ellipsis" or isinstance(e, ast.Starred):
-                continue
-            else:
-                bad.append(e)
-        return bad
-
-    def check(self, module, project):
-        if not self._uses_pallas(module):
-            return []
-        out = []
-        for node in ast.walk(module.tree):
-            if isinstance(node, ast.Subscript):
-                base = node.value
-                if not (isinstance(base, ast.Name)
-                        and (base.id.endswith("_ref") or base.id == "ref")):
-                    continue
-                for e in self._dynamic_elements(module, node.slice):
-                    out.append(module.finding(
-                        self.rule_name, node.lineno,
-                        f"bare dynamic scalar index on Pallas ref "
-                        f"{base.id!r}; use pl.ds(i, 1) (bare scalars break "
-                        f"0.4.x interpret mode)"))
-            elif isinstance(node, ast.Call):
-                canon = module.canonical(node.func) or ""
-                if not (canon.startswith("jax.experimental.pallas.")
-                        and canon.rsplit(".", 1)[-1] in self._LOAD_STORE):
-                    continue
-                if len(node.args) < 2:
-                    continue
-                for e in self._dynamic_elements(module, node.args[1]):
-                    out.append(module.finding(
-                        self.rule_name, node.lineno,
-                        "bare dynamic scalar index in pl.load/pl.store; "
-                        "use pl.ds(i, 1)"))
+                    f"through repro.launch.mesh.make_mesh (one place "
+                    f"sets axis_types)"))
         return out
 
 
@@ -799,7 +696,7 @@ class F64Rule(Rule):
                 self.rule_name, node.lineno,
                 f"{canon} outside an enable_x64 guard silently truncates to "
                 f"32 bit under the default config; guard with "
-                f"jax.experimental.enable_x64 or mark the traced callee "
+                f"jax.enable_x64(True) or mark the traced callee "
                 f"`# analysis: x64-ok`"))
         return out
 

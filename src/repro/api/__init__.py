@@ -55,6 +55,7 @@ from repro.api.sweep import (  # noqa: F401
     ShardPlan,
     SweepCellSpec,
     SweepVariant,
+    lower_sweep,
     resolve_shard,
     run_lockstep_sweep,
     run_sweep,
@@ -106,6 +107,7 @@ __all__ = [
     "get_compressor",
     "get_delay",
     "get_solver",
+    "lower_sweep",
     "register_compressor",
     "register_delay",
     "register_solver",

@@ -49,9 +49,10 @@ device mesh (:func:`repro.launch.mesh.make_mesh` + ``shard_map``):
 
 Timing/byte accounting stays host-side for lockstep
 (:func:`repro.core.executor.lockstep_accounts` -- per (delay, seed), since
-gamma does not move the simulated clock) and comes back as per-round scan
-outputs for lag; the deferred gap certificates of ALL variants evaluate in
-one bucketed ``lax.map`` dispatch.
+gamma does not move the simulated clock) and is replayed on the host from
+each lag cell's per-round pop order and byte counts
+(:func:`repro.core.executor.lag_accounts`); the deferred gap certificates
+of ALL variants evaluate in one bucketed ``lax.map`` dispatch.
 
 The group-family protocols (data-dependent arrival control flow) cannot
 batch this way; sweep them with one :class:`repro.api.Session` per cell.
@@ -66,7 +67,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import compress as compress_lib
@@ -226,10 +226,10 @@ def _sweep_scan(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, eval_idx, *,
         return block(keys, X, y, norms_sq, lam, n, sigma_ps, gammas,
                      eval_idx)
     mesh = mesh_lib.make_sweep_mesh(n_shards, "cells")
-    fn = shard_map(block, mesh=mesh,
+    fn = jax.shard_map(block, mesh=mesh,
                    in_specs=(P("cells"), P(), P(), P(), P(), P(),
                              P("cells"), P("cells"), P()),
-                   out_specs=(P("cells"),) * 4, check_rep=False)
+                   out_specs=(P("cells"),) * 4, check_vma=False)
     return fn(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, eval_idx)
 
 
@@ -263,12 +263,12 @@ def _sweep_scan_workers(keys, X, y, norms_sq, lam, n, sigma_ps, gammas,
             return jax.vmap(one)(keys, sigma_ps, gammas)
         return jax.lax.map(lambda a: one(*a), (keys, sigma_ps, gammas))
 
-    fn = shard_map(block, mesh=mesh,
+    fn = jax.shard_map(block, mesh=mesh,
                    in_specs=(P(), P("workers"), P("workers"), P("workers"),
                              P(), P(), P(), P(), P()),
                    out_specs=(P(), P(None, "workers"), P(),
                               P(None, None, "workers")),
-                   check_rep=False)
+                   check_vma=False)
     return fn(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, eval_idx)
 
 
@@ -298,9 +298,10 @@ def _lag_sweep_scan(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, xi,
             heartbeat_bytes, lat, bw, lf, loss=loss, num_steps=num_steps,
             comp=comp, length=length, lag_window=lag_window,
             dense_reply_bytes=dense_reply_bytes)
-        ws, app_rows, sim, bu, bd, ct, cm = ys
+        ws, app_rows, order, reply_bytes, launch_bytes = ys
         return (state["w_server"], state["alpha"], state["alpha_applied"],
-                ws[idx], app_rows[idx], sim, bu, bd, ct, cm)
+                ws[idx], app_rows[idx], state["init_bytes"], order,
+                reply_bytes, launch_bytes)
 
     def block(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, xi, durations,
               needs, up_bytes, heartbeat_bytes, latencies, bandwidths,
@@ -322,10 +323,10 @@ def _lag_sweep_scan(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, xi,
         return block(*args)
     mesh = mesh_lib.make_sweep_mesh(n_shards, "cells")
     cell = P("cells")
-    fn = shard_map(block, mesh=mesh,
+    fn = jax.shard_map(block, mesh=mesh,
                    in_specs=(cell, P(), P(), P(), P(), P(), cell, cell, P(),
                              cell, P(), P(), P(), cell, cell, cell, P()),
-                   out_specs=(cell,) * 10, check_rep=False)
+                   out_specs=(cell,) * 9, check_vma=False)
     return fn(*args)
 
 
@@ -408,6 +409,45 @@ def run_sweep(
     ``Session(executor="scan")`` run -- and therefore to the event engine
     (pinned by tests/test_sweep.py).
     """
+    variants, cells, plan = _sweep_grid(
+        problem, method, cluster, num_outer=num_outer, seeds=seeds,
+        gammas=gammas, delays=delays, batch=batch, shard=shard)
+    if method.protocol == "lag":
+        return _lag_cells(problem, method, cells, num_outer=num_outer,
+                          eval_every=eval_every, batch=batch, plan=plan)
+    return _run_lockstep_sweep(problem, method, variants, cells,
+                               num_outer=num_outer, eval_every=eval_every,
+                               batch=batch, plan=plan)
+
+
+def lower_sweep(problem, method, cluster, *, num_outer: int, seeds=(0,),
+                gammas=None, delays=None, eval_every: int = 1,
+                batch: str = "vmap", shard: str = "auto") -> jax.stages.Lowered:
+    """The computation :func:`run_sweep` would dispatch for these arguments,
+    lowered and not run: ``.compile().memory_analysis()`` sizes a grid for a
+    device before running it."""
+    variants, cells, plan = _sweep_grid(
+        problem, method, cluster, num_outer=num_outer, seeds=seeds,
+        gammas=gammas, delays=delays, batch=batch, shard=shard)
+    if method.protocol == "lag":
+        with jax.enable_x64(True):
+            fn, args, kw, _, _ = _lag_call(problem, method, cells,
+                                        num_outer=num_outer,
+                                        eval_every=eval_every, batch=batch,
+                                        plan=plan)
+            return fn.lower(*args, **kw)
+    block = cells[:len(cells) // len(variants)]  # see _run_lockstep_sweep
+    fn, args, kw, _ = _lockstep_call(problem, method, block,
+                                     num_outer=num_outer,
+                                     eval_every=eval_every, batch=batch,
+                                     plan=plan)
+    return fn.lower(*args, **kw)
+
+
+def _sweep_grid(problem, method, cluster, *, num_outer, seeds, gammas,
+                delays, batch, shard):
+    """Validate a :func:`run_sweep` request; returns ``(delay variants,
+    cells delay-major then seed then gamma, shard plan)``."""
     if method.protocol not in executor.SWEEP_PROTOCOLS:
         raise ValueError(
             f"sweep batching needs a sweep-batchable (shared-cell "
@@ -430,14 +470,12 @@ def run_sweep(
                          "None to keep the cluster's own delay model")
     plan = resolve_shard(shard, protocol=method.protocol,
                          num_workers=problem.X.shape[0])
-    if method.protocol == "lag":
-        return _run_lag_sweep(problem, method, variants, num_outer=num_outer,
-                              seeds=seeds, gammas=gammas,
-                              eval_every=eval_every, batch=batch, plan=plan)
-    return _run_lockstep_sweep(problem, method, variants,
-                               num_outer=num_outer, seeds=seeds,
-                               gammas=gammas, eval_every=eval_every,
-                               batch=batch, plan=plan)
+    # The cell-level cores key duration streams by the (hashable)
+    # ClusterModel itself, NOT the delay name: two entries of the same model
+    # with different params must not share a stream.
+    cells = [SweepCellSpec(cl, s, g, method.sigma_prime)
+             for _, cl in variants for s in seeds for g in gammas]
+    return variants, cells, plan
 
 
 def _variant_records(rounds, evals, gap, gap_srv, p, dv, v):
@@ -471,77 +509,71 @@ def _eval_grid(ws_eval, alphas_eval, problem, V, S):
                  for a in (p, dv, gap, gap_srv))
 
 
-def _run_lockstep_sweep(problem, method, variants, *, num_outer, seeds,
-                        gammas, eval_every, batch, plan):
-    K, n_k, d = problem.X.shape
+def _run_lockstep_sweep(problem, method, variants, cells, *, num_outer,
+                        eval_every, batch, plan):
+    d = problem.X.shape[2]
     # Trajectories depend only on (seed, gamma): the delay axis is pure
-    # host-side accounting for lockstep runs, so compute each unique
-    # trajectory once and reuse it across delay variants.
-    cells = [(s, g) for s in seeds for g in gammas]
-    methods = {g: dataclasses.replace(method, gamma=g) for g in gammas}
-    padded = _padded_cells(cells, plan.n_shards)
-    sigma_ps = np.asarray([methods[g].resolved_sigma_prime(K)
-                           for _, g in padded])
-    keys = jax.vmap(jax.random.key)(jnp.asarray([s for s, _ in padded]))
-    norms_sq = jnp.sum(problem.X * problem.X, axis=-1)
-    evals = executor._eval_indices(num_outer, eval_every)
-
+    # host-side accounting for lockstep runs, so the first delay block's
+    # cells run once and every delay variant reuses them.
+    block = cells[:len(cells) // len(variants)]
+    fn, args, kw, evals = _lockstep_call(problem, method, block,
+                                         num_outer=num_outer,
+                                         eval_every=eval_every, batch=batch,
+                                         plan=plan)
     executor.STATS["sweep_calls"] += 1
-    runner = _sweep_scan if plan.mode != "workers" else partial(
-        _sweep_scan_workers, num_workers=K)
-    w, alpha, ws_eval, alphas_eval = runner(
-        keys, problem.X, problem.y, norms_sq, problem.lam, K * n_k,
-        jnp.asarray(sigma_ps, problem.X.dtype),
-        jnp.asarray([g for _, g in padded], problem.X.dtype),
-        jnp.asarray(_padded_eval_idx(evals), jnp.int32),
-        loss=problem.loss, num_steps=method.H,
-        solver=executor.lockstep_solver(method), length=num_outer,
-        batch=batch, n_shards=plan.n_shards if plan.mode != "none" else 1)
-
-    V, S = len(cells), len(evals)
+    w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
+    V, S = len(block), len(evals)
     p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], alphas_eval[:V, :S],
                                      problem, V, S)
     # Gamma does not move the simulated clock: accounting is per
     # (delay variant, seed).
     out = []
     for name, cl in variants:
-        accounts = {s: executor.lockstep_accounts(
-            method, cl, d, num_rounds=num_outer, seed=s) for s in seeds}
-        for v, (seed, gamma) in enumerate(cells):
-            records = _variant_records(accounts[seed], evals, gap, gap_srv,
-                                       p, dv, v)
-            out.append(SweepVariant(seed, gamma, RunResult(
-                methods[gamma], records, np.asarray(w[v]),
-                np.asarray(alpha[v])), delay=name))
+        accounts = {c.seed: executor.lockstep_accounts(
+            method, cl, d, num_rounds=num_outer, seed=c.seed) for c in block}
+        for v, c in enumerate(block):
+            records = _variant_records(accounts[c.seed], evals, gap,
+                                       gap_srv, p, dv, v)
+            out.append(SweepVariant(c.seed, c.gamma, RunResult(
+                dataclasses.replace(method, gamma=c.gamma), records,
+                np.asarray(w[v]), np.asarray(alpha[v])), delay=name))
     return out
 
 
-def _run_lag_sweep(problem, method, variants, *, num_outer, seeds, gammas,
-                   eval_every, batch, plan):
-    # Cell order: delay-major, then seed, then gamma (matches the returned
-    # variant order).  The cell-level core below keys duration streams by
-    # the (hashable) ClusterModel itself, NOT the delay name: two entries
-    # of the same model with different params must not share a stream.
-    cells = [SweepCellSpec(cl, s, g, method.sigma_prime)
-             for _, cl in variants for s in seeds for g in gammas]
-    return _lag_cells(problem, method, cells, num_outer=num_outer,
-                      eval_every=eval_every, batch=batch, plan=plan)
-
-
-def _lag_cells(problem, method, cells, *, num_outer, eval_every, batch,
-               plan):
-    from jax.experimental import enable_x64
-
+def _lockstep_call(problem, method, cells, *, num_outer, eval_every, batch,
+                   plan):
+    """The one lockstep sweep dispatch for ``cells``: ``(jitted fn, args,
+    static kwargs, eval-boundary rounds)``."""
     K, n_k, d = problem.X.shape
-    T = method.T
-    R = num_outer * T
+    padded = _padded_cells(list(cells), plan.n_shards)
+    sigma_ps = np.asarray([dataclasses.replace(
+        method, gamma=c.gamma,
+        sigma_prime=c.sigma_prime).resolved_sigma_prime(K) for c in padded])
+    keys = jax.vmap(jax.random.key)(jnp.asarray([c.seed for c in padded]))
+    norms_sq = jnp.sum(problem.X * problem.X, axis=-1)
+    evals = executor._eval_indices(num_outer, eval_every)
+    fn = _sweep_scan if plan.mode != "workers" else partial(
+        _sweep_scan_workers, num_workers=K)
+    args = (keys, problem.X, problem.y, norms_sq, problem.lam, K * n_k,
+            jnp.asarray(sigma_ps, problem.X.dtype),
+            jnp.asarray([c.gamma for c in padded], problem.X.dtype),
+            jnp.asarray(_padded_eval_idx(evals), jnp.int32))
+    kw = dict(loss=problem.loss, num_steps=method.H,
+              solver=executor.lockstep_solver(method), length=num_outer,
+              batch=batch,
+              n_shards=plan.n_shards if plan.mode != "none" else 1)
+    return fn, args, kw, evals
+
+
+def _lag_call(problem, method, cells, *, num_outer, eval_every, batch,
+              plan):
+    """The one LAG sweep dispatch for ``cells``: ``(jitted fn, args, static
+    kwargs, eval-boundary rounds, host timing per cell)``.  Build it under
+    ``enable_x64``: the timing operands are float64."""
+    K, n_k, d = problem.X.shape
+    R = num_outer * method.T
     comp = compress_lib.for_method(method, d)
     dense = isinstance(comp, compress_lib.Dense)
-    up_bytes = comp.wire_bytes(d)
-    needs = executor.lag_needs(method, K, R)
-    mcfgs = [dataclasses.replace(method, gamma=c.gamma,
-                                 sigma_prime=c.sigma_prime) for c in cells]
-
     for c in cells:
         ok, why = executor.scan_supported(method, c.cluster)
         if not ok:
@@ -572,36 +604,54 @@ def _lag_cells(problem, method, cells, *, num_outer, eval_every, batch,
         jnp.asarray([c.seed for c in padded]))
     norms_sq = jnp.sum(problem.X * problem.X, axis=-1)
     evals = executor._eval_indices(R, eval_every)
-
-    executor.STATS["sweep_lag_calls"] += 1
-    with enable_x64():
-        (w, alpha, alpha_applied, ws_eval, app_eval, sim, bu, bd, ct,
-         cm) = _lag_sweep_scan(
-            keys, problem.X, problem.y, norms_sq, jnp.float32(problem.lam),
+    args = (keys, problem.X, problem.y, norms_sq, jnp.float32(problem.lam),
             jnp.int32(K * n_k), jnp.asarray(sigma_ps, jnp.float32),
             jnp.asarray([c.gamma for c in padded], jnp.float32),
             jnp.float32(method.lag_xi),
             jnp.asarray(durations, jnp.float64),
-            jnp.asarray(needs, jnp.int64),
-            jnp.asarray(up_bytes, jnp.int64),
+            jnp.asarray(executor.lag_needs(method, K, R), jnp.int64),
+            jnp.asarray(comp.wire_bytes(d), jnp.int64),
             jnp.asarray(engine.LagProtocol.HEARTBEAT_BYTES, jnp.int64),
             jnp.asarray(lats, jnp.float64),
             jnp.asarray(bws, jnp.float64),
             jnp.asarray(link_factors, jnp.float64),
-            jnp.asarray(_padded_eval_idx(evals), jnp.int32),
-            loss=problem.loss, num_steps=method.H, comp=comp, length=R,
-            lag_window=method.lag_window,
-            dense_reply_bytes=d * 4 if dense else 0, batch=batch,
-            n_shards=plan.n_shards if plan.mode == "cells" else 1)
+            jnp.asarray(_padded_eval_idx(evals), jnp.int32))
+    kw = dict(loss=problem.loss, num_steps=method.H, comp=comp, length=R,
+              lag_window=method.lag_window,
+              dense_reply_bytes=d * 4 if dense else 0, batch=batch,
+              n_shards=plan.n_shards if plan.mode == "cells" else 1)
+    timing = (durations, link_factors, lats, bws)  # per padded cell, host
+    return _lag_sweep_scan, args, kw, evals, timing
+
+
+def _lag_cells(problem, method, cells, *, num_outer, eval_every, batch,
+               plan):
+    K = problem.X.shape[0]
+    T = method.T
+    needs = executor.lag_needs(method, K, num_outer * T)
+    mcfgs = [dataclasses.replace(method, gamma=c.gamma,
+                                 sigma_prime=c.sigma_prime) for c in cells]
+    with jax.enable_x64(True):
+        fn, args, kw, evals, timing = _lag_call(
+            problem, method, cells, num_outer=num_outer,
+            eval_every=eval_every, batch=batch, plan=plan)
+        executor.STATS["sweep_lag_calls"] += 1
+        (w, alpha, alpha_applied, ws_eval, app_eval, init_bytes, order,
+         reply_bytes, launch_bytes) = fn(*args, **kw)
 
     V, S = len(cells), len(evals)
     p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], app_eval[:V, :S],
                                      problem, V, S)
-    sim, bu, bd, ct, cm = (np.asarray(a) for a in (sim, bu, bd, ct, cm))
+    init_bytes, order, reply_bytes, launch_bytes = (
+        np.asarray(a) for a in (init_bytes, order, reply_bytes,
+                                launch_bytes))
+    durations, link_factors, lats, bws = timing
     out = []
     for v, c in enumerate(cells):
-        rounds = executor.lag_accounts(needs, T, sim[v], bu[v], bd[v],
-                                       ct[v], cm[v])
+        rounds = executor.lag_accounts(
+            needs, T, durations[v], link_factors[v], float(lats[v]),
+            float(bws[v]), init_bytes[v], order[v], reply_bytes[v],
+            launch_bytes[v])
         records = _variant_records(rounds, evals, gap, gap_srv, p, dv, v)
         out.append(SweepVariant(c.seed, c.gamma, RunResult(
             mcfgs[v], records, np.asarray(w[v]), np.asarray(alpha[v]),
@@ -612,28 +662,15 @@ def _lag_cells(problem, method, cells, *, num_outer, eval_every, batch,
 
 def _lockstep_cells(problem, method, cells, *, num_outer, eval_every, batch,
                     plan):
-    K, n_k, d = problem.X.shape
+    d = problem.X.shape[2]
     mcfgs = [dataclasses.replace(method, gamma=c.gamma,
                                  sigma_prime=c.sigma_prime) for c in cells]
-    padded = _padded_cells(list(cells), plan.n_shards)
-    sigma_ps = np.asarray([dataclasses.replace(
-        method, gamma=c.gamma,
-        sigma_prime=c.sigma_prime).resolved_sigma_prime(K) for c in padded])
-    keys = jax.vmap(jax.random.key)(jnp.asarray([c.seed for c in padded]))
-    norms_sq = jnp.sum(problem.X * problem.X, axis=-1)
-    evals = executor._eval_indices(num_outer, eval_every)
-
+    fn, args, kw, evals = _lockstep_call(problem, method, cells,
+                                         num_outer=num_outer,
+                                         eval_every=eval_every, batch=batch,
+                                         plan=plan)
     executor.STATS["sweep_calls"] += 1
-    runner = _sweep_scan if plan.mode != "workers" else partial(
-        _sweep_scan_workers, num_workers=K)
-    w, alpha, ws_eval, alphas_eval = runner(
-        keys, problem.X, problem.y, norms_sq, problem.lam, K * n_k,
-        jnp.asarray(sigma_ps, problem.X.dtype),
-        jnp.asarray([c.gamma for c in padded], problem.X.dtype),
-        jnp.asarray(_padded_eval_idx(evals), jnp.int32),
-        loss=problem.loss, num_steps=method.H,
-        solver=executor.lockstep_solver(method), length=num_outer,
-        batch=batch, n_shards=plan.n_shards if plan.mode != "none" else 1)
+    w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
 
     V, S = len(cells), len(evals)
     p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], alphas_eval[:V, :S],

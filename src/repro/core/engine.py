@@ -328,7 +328,7 @@ def _worker_rounds_lag_fused(key, w_local, alpha, residual, ref_buf, ref_len,
         key, alpha_k, res_k, dw, sent = _local_round(
             key, w_local, alpha[k], residual[k], X[k], y[k], norms_sq[k], k,
             lam, n, sigma_p, gamma, loss=loss, num_steps=num_steps, comp=comp)
-        send_sq = jnp.vdot(sent, sent)
+        send_sq = jnp.vdot(sent, sent, precision=objectives.HIGHEST)
         skip = send_sq < ref_k
         sent = jnp.where(skip, jnp.zeros_like(sent), sent)
         res_k = jnp.where(skip, dw, res_k)

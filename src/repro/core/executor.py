@@ -23,10 +23,13 @@ Three scan paths:
   ``nnz`` -> reply bytes -> link time -> arrival order), so the *event queue
   itself* moves in-graph: per-worker arrival times and sequence numbers live
   in the scan carry, the B earliest messages are selected with a
-  lexicographic ``lax.sort``, and all timing arithmetic runs in float64 on
-  device (traced under ``jax.experimental.enable_x64``; model math stays
-  explicitly float32, and ``sdca`` pins its PRNG dtypes, so the f32
-  trajectory is bit-identical to the event executor's).  Eligible whenever
+  lexicographic ``lax.sort`` over float64 arrival times (traced under
+  ``jax.enable_x64(True)``; model math stays explicitly float32, and
+  ``sdca`` pins its PRNG dtypes, so the f32 trajectory is bit-identical to
+  the event executor's).  The clocks and byte totals are replayed on the
+  host from the device's pop order and byte counts
+  (:func:`lag_accounts`), because a TPU's float64 is emulated without IEEE
+  rounding.  Eligible whenever
   the delay model can pre-sample ``(round, worker)`` compute times without
   changing the event executor's RNG stream (``sample_stream`` contract);
   ``markov`` and jittered ``constant`` cannot, and ``executor="auto"`` falls
@@ -607,9 +610,16 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
     relaunches the arrived workers under a ``lax.cond``-guarded rank scan
     that splits the global PRNG key only for launched workers (the event
     path's sequential split chain).  Must be traced under ``enable_x64`` so
-    the timing arithmetic is float64 like the host's; all model math is
-    pinned float32.  ``dense_reply_bytes`` is 0 for sparse compressors
-    (replies billed on in-graph nnz) or the static dense byte count.
+    the arrival times are float64 like the host's; all model math is pinned
+    float32.  ``dense_reply_bytes`` is 0 for sparse compressors (replies
+    billed on in-graph nnz) or the static dense byte count.
+
+    The device times arrivals only to order them.  A TPU emulates float64
+    without IEEE rounding, so the clocks and byte totals are not summed
+    here: the run returns its decisions -- per round the pop order, each
+    rank's reply bytes and each launch's upload bytes (``state
+    ["init_bytes"]`` for the t=0 wave) -- and :func:`lag_accounts` replays
+    the accounting on the host.
 
     Shared by the single-run jit below and the batched sweep runner
     (:mod:`repro.api.sweep`), which maps/vmaps it over delay x seed x gamma
@@ -623,67 +633,48 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
     iota = jnp.arange(K, dtype=i64)
 
     def launch(args, *, initial):
-        """Rank-scan relaunching the first ``need`` ranks of ``order``."""
+        """Rank-scan relaunching the first ``need`` ranks of ``order``;
+        returns the carried state plus each rank's upload bytes (0 for a
+        rank that does not launch)."""
         (key, alpha, residual, payload, applied, arrival, seq, seq_ctr,
-         bytes_up, bytes_down, compute_t, comm_t, ref_buf, ref_len, w_local,
-         need, order, starts, reply_bytes, down_times, dur_row) = args
+         ref_buf, ref_len, w_local, need, order, starts, dur_row) = args
 
         def do_launch(carry, xs):
-            (key, alpha, residual, payload, applied, arrival, seq,
-             compute_t, comm_t, bytes_up, bytes_down) = carry
-            j, k, start, rbytes, down_t = xs
+            key, alpha, residual, payload, applied, arrival, seq = carry
+            j, k, start = xs
             ref_k = engine._lag_reference(ref_buf[k], ref_len[k], xi)
             key, alpha_k, res_k, dw, sent = engine._local_round(
                 key, w_local, alpha[k], residual[k], X[k], y[k], norms_sq[k],
                 k, lam, n, sigma_p, gamma, loss=loss, num_steps=num_steps,
                 comp=comp)
-            send_sq = jnp.vdot(sent, sent)
+            send_sq = jnp.vdot(sent, sent, precision=objectives.HIGHEST)
             skip = send_sq < ref_k
             sent = jnp.where(skip, jnp.zeros_like(sent), sent)
             res_k = jnp.where(skip, dw, res_k)
             nbytes = jnp.where(skip, heartbeat_bytes, up_bytes)
-            # Host accounting replica, per worker in arrival order:
-            # down-billing, compute, up-billing (the reference float order).
-            dur = dur_row[k]
             up_t = latency + nbytes * link_factors[k] / bandwidth
-            bytes_down = bytes_down + rbytes
-            comm_t = comm_t + down_t
-            compute_t = compute_t + dur
-            comm_t = comm_t + up_t
-            bytes_up = bytes_up + nbytes
             alpha = alpha.at[k].set(alpha_k)
             residual = residual.at[k].set(res_k)
             payload = payload.at[k].set(sent)
             applied = applied.at[k].set(~skip)
-            arrival = arrival.at[k].set(start + dur + up_t)
+            arrival = arrival.at[k].set(start + dur_row[k] + up_t)
             seq = seq.at[k].set(seq_ctr + j + 1)
-            return (key, alpha, residual, payload, applied, arrival, seq,
-                    compute_t, comm_t, bytes_up, bytes_down), None
+            return (key, alpha, residual, payload, applied, arrival,
+                    seq), nbytes
 
         def no_op(carry, xs):
-            return carry, None
+            return carry, jnp.zeros((), i64)
 
         def rank_body(carry, xs):
             return jax.lax.cond(xs[0] < need, do_launch, no_op, carry, xs)
 
-        init = (key, alpha, residual, payload, applied, arrival, seq,
-                compute_t, comm_t, bytes_up, bytes_down)
-        if initial:
-            # No ambiguity on the first launch: every worker, worker order.
-            out, _ = jax.lax.scan(do_launch, init,
-                                  (iota, order, starts, reply_bytes,
-                                   down_times))
-        else:
-            out, _ = jax.lax.scan(rank_body, init,
-                                  (iota, order, starts, reply_bytes,
-                                   down_times))
-        (key, alpha, residual, payload, applied, arrival, seq, compute_t,
-         comm_t, bytes_up, bytes_down) = out
-        return (key, alpha, residual, payload, applied, arrival, seq,
-                seq_ctr + need, bytes_up, bytes_down, compute_t, comm_t)
+        init = (key, alpha, residual, payload, applied, arrival, seq)
+        # No ambiguity on the first launch: every worker, worker order.
+        out, nbytes = jax.lax.scan(do_launch if initial else rank_body, init,
+                                   (iota, order, starts))
+        return out + (seq_ctr + need,), nbytes
 
     # --- initial state + the t=0 launch wave ------------------------------
-    zero64 = jnp.zeros((), f64)
     state = dict(
         key=key,
         w_server=jnp.zeros((d,), dt),
@@ -699,22 +690,14 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
         arrival=jnp.zeros((K,), f64),
         seq=jnp.zeros((K,), i64),
         seq_ctr=jnp.zeros((), i64),
-        bytes_up=jnp.zeros((), i64),
-        bytes_down=jnp.zeros((), i64),
-        compute_t=zero64,
-        comm_t=zero64,
-        sim_time=zero64,
     )
     (state["key"], state["alpha"], state["residual"], state["payload"],
-     state["applied"], state["arrival"], state["seq"], state["seq_ctr"],
-     state["bytes_up"], state["bytes_down"], state["compute_t"],
-     state["comm_t"]) = launch(
+     state["applied"], state["arrival"], state["seq"],
+     state["seq_ctr"]), init_bytes = launch(
         (state["key"], state["alpha"], state["residual"], state["payload"],
          state["applied"], state["arrival"], state["seq"], state["seq_ctr"],
-         state["bytes_up"], state["bytes_down"], state["compute_t"],
-         state["comm_t"], state["ref_buf"], state["ref_len"],
-         state["w_local"], jnp.asarray(K, i64), iota, jnp.zeros((K,), f64),
-         jnp.zeros((K,), i64), jnp.zeros((K,), f64), durations[0]),
+         state["ref_buf"], state["ref_len"], state["w_local"],
+         jnp.asarray(K, i64), iota, jnp.zeros((K,), f64), durations[0]),
         initial=True)
 
     # --- the round loop ---------------------------------------------------
@@ -725,8 +708,7 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
         need = need.astype(i64)
         # Pop order: lexicographic (arrival, seq) -- the host heap's order.
         _, _, perm = jax.lax.sort((s["arrival"], s["seq"], iota), num_keys=2)
-        sorted_arrival = s["arrival"][perm]
-        server_time = sorted_arrival[need - 1]
+        server_time = s["arrival"][perm][need - 1]
         sel = iota < need
 
         # Aggregation, summed in arrival order over exactly `need` payloads.
@@ -769,30 +751,27 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
             reply_bytes = jnp.full((K,), dense_reply_bytes, i64)
         else:
             reply_bytes = (reply_nnz * 8).astype(i64)
-        factors = link_factors[perm]
-        down_times = latency + reply_bytes * factors / bandwidth
+        down_times = latency + reply_bytes * link_factors[perm] / bandwidth
         starts = server_time + down_times
 
-        (key, alpha, residual, payload, applied, arrival, seq, seq_ctr,
-         bytes_up, bytes_down, compute_t, comm_t) = launch(
+        (key, alpha, residual, payload, applied, arrival, seq,
+         seq_ctr), launch_bytes = launch(
             (s["key"], s["alpha"], s["residual"], s["payload"], s["applied"],
-             s["arrival"], s["seq"], s["seq_ctr"], s["bytes_up"],
-             s["bytes_down"], s["compute_t"], s["comm_t"], ref_buf, ref_len,
-             w_local, need, perm, starts, reply_bytes, down_times, dur_row),
+             s["arrival"], s["seq"], s["seq_ctr"], ref_buf, ref_len, w_local,
+             need, perm, starts, dur_row),
             initial=False)
 
         s.update(key=key, w_server=w_server, dw_tilde=dw_tilde,
                  w_local=w_local, alpha=alpha, alpha_applied=alpha_applied,
                  residual=residual, payload=payload, applied=applied,
                  ref_buf=ref_buf, ref_len=ref_len, arrival=arrival, seq=seq,
-                 seq_ctr=seq_ctr, bytes_up=bytes_up, bytes_down=bytes_down,
-                 compute_t=compute_t, comm_t=comm_t, sim_time=server_time)
-        ys = (w_server, alpha_applied, server_time, bytes_up, bytes_down,
-              compute_t, comm_t)
+                 seq_ctr=seq_ctr)
+        ys = (w_server, alpha_applied, perm, reply_bytes, launch_bytes)
         return s, ys
 
     state, ys = jax.lax.scan(round_step, state,
                              (needs, durations[1:]), length=length)
+    state["init_bytes"] = init_bytes
     return state, ys
 
 
@@ -840,21 +819,68 @@ def lag_durations(method: MethodConfig, cluster: ClusterModel, *,
     return durations, delay
 
 
-def lag_accounts(needs: np.ndarray, T: int, sim, bu, bd, ct,
-                 cm) -> list[RoundAccount]:
-    """RoundAccounts from one lag run's per-round scan outputs (host arrays)."""
-    sim = np.asarray(sim)
-    bu, bd = np.asarray(bu), np.asarray(bd)
-    ct, cm = np.asarray(ct), np.asarray(cm)
-    return [RoundAccount(int(needs[r]), r % T == T - 1, float(sim[r]),
-                         int(bu[r]), int(bd[r]), float(ct[r]), float(cm[r]))
-            for r in range(len(needs))]
+def lag_accounts(needs, T: int, durations, link_factors, latency: float,
+                 bandwidth: float, init_bytes, order, reply_bytes,
+                 launch_bytes) -> list[RoundAccount]:
+    """One lag run's RoundAccounts, replayed on the host from the device's
+    decisions (:func:`lag_run_traced`): per round the pop ``order``, each
+    rank's ``reply_bytes`` and each launch's upload bytes.
+
+    The replay is the event executor's float64 arithmetic in its order
+    (reply billing, compute, upload, per arrival), so the clocks match it
+    bit for bit on any backend.  It re-derives the pop order from its own
+    IEEE arrival times and raises if the device ever popped differently --
+    a TPU's emulated float64 could only reorder a near tie.
+    """
+    K = len(link_factors)
+    lf = np.asarray(link_factors, np.float64)
+    durations = np.asarray(durations, np.float64)
+    order, reply_bytes, launch_bytes = (
+        np.asarray(a) for a in (order, reply_bytes, launch_bytes))
+    init_bytes = np.asarray(init_bytes)
+    arrival = np.zeros(K)
+    seq = np.zeros(K, np.int64)
+    bu = bd = 0
+    ct = cm = 0.0
+    for k in range(K):  # the t=0 wave: every worker, worker order
+        nb = int(init_bytes[k])
+        up_t = latency + nb * lf[k] / bandwidth
+        ct += durations[0, k]
+        cm += up_t
+        bu += nb
+        arrival[k] = 0.0 + durations[0, k] + up_t
+        seq[k] = k + 1
+    seq_ctr = K
+    rounds = []
+    for r, need in enumerate(int(x) for x in needs):
+        popped = np.lexsort((seq, arrival))[:need]
+        if not np.array_equal(popped, order[r, :need]):
+            raise RuntimeError(
+                f"round {r}: the device popped workers "
+                f"{order[r, :need].tolist()} but the float64 arrival times "
+                f"order them {popped.tolist()}; run it with "
+                f"executor='event'")
+        server_time = arrival[popped[-1]]
+        for j, k in enumerate(popped):
+            rb = int(reply_bytes[r, j])
+            down_t = latency + rb * lf[k] / bandwidth
+            bd += rb
+            cm += down_t
+            ct += durations[r + 1, k]
+            nb = int(launch_bytes[r, j])
+            up_t = latency + nb * lf[k] / bandwidth
+            cm += up_t
+            bu += nb
+            arrival[k] = server_time + down_t + durations[r + 1, k] + up_t
+            seq[k] = seq_ctr + j + 1
+        seq_ctr += need
+        rounds.append(RoundAccount(need, r % T == T - 1, float(server_time),
+                                   bu, bd, float(ct), float(cm)))
+    return rounds
 
 
 def _run_lag(problem, method, cluster, *, num_outer, seed, eval_every,
              norms_sq):
-    from jax.experimental import enable_x64
-
     K, n_k, d = problem.X.shape
     T = method.T
     R = num_outer * T
@@ -871,7 +897,7 @@ def _run_lag(problem, method, cluster, *, num_outer, seed, eval_every,
                        alpha_applied=jnp.zeros((K, n_k), dt))
 
     STATS["lag_calls"] += 1
-    with enable_x64():
+    with jax.enable_x64(True):
         state, ys = _lag_scan(
             jax.random.key(seed), problem.X, problem.y, norms_sq,
             jnp.float32(problem.lam), jnp.int32(K * n_k),
@@ -888,8 +914,11 @@ def _run_lag(problem, method, cluster, *, num_outer, seed, eval_every,
             lag_window=method.lag_window,
             dense_reply_bytes=d * 4 if dense else 0)
 
-    ws, alpha_applied_rows, sim, bu, bd, ct, cm = ys
-    rounds = lag_accounts(needs, T, sim, bu, bd, ct, cm)
+    ws, alpha_applied_rows, order, reply_bytes, launch_bytes = ys
+    rounds = lag_accounts(needs, T, durations, delay.link_factors(),
+                          cluster.latency, cluster.bandwidth,
+                          state["init_bytes"], order, reply_bytes,
+                          launch_bytes)
     evals = _eval_indices(R, eval_every)
     idx = jnp.asarray(evals, jnp.int32)
     return ScanRun(method, rounds, evals, ws[idx], alpha_applied_rows[idx],
@@ -1169,8 +1198,6 @@ def partial_durations(method: MethodConfig, cluster: ClusterModel, *,
 
 def _run_partial(problem, method, cluster, *, num_outer, seed, eval_every,
                  norms_sq):
-    from jax.experimental import enable_x64
-
     K, n_k, d = problem.X.shape
     T = method.T
     R = num_outer * T
@@ -1191,7 +1218,7 @@ def _run_partial(problem, method, cluster, *, num_outer, seed, eval_every,
     sigma_p = method.resolved_sigma_prime(K)
 
     STATS["partial_calls"] += 1
-    with enable_x64():
+    with jax.enable_x64(True):
         state, ys = _partial_scan(
             jax.random.key(seed), problem.X, problem.y, norms_sq,
             jnp.float32(problem.lam), jnp.int32(K * n_k),

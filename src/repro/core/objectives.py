@@ -33,6 +33,10 @@ import jax.numpy as jnp
 
 LossName = Literal["ridge", "smoothed_hinge", "logistic"]
 
+# Full f32 products: a TPU runs a DEFAULT-precision f32 dot in one bf16
+# pass, and the gap is a small difference of two such sums.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 # Smoothing constant for the smoothed hinge (gamma-bar in SSZ'13); phi is
 # (1/mu)-smooth with mu == _HINGE_SMOOTHING.
 _HINGE_SMOOTHING = 1.0
@@ -161,9 +165,10 @@ def smoothness_mu(loss: LossName) -> float:
 @partial(jax.jit, static_argnames=("loss",))
 def primal_objective(w: jax.Array, X: jax.Array, y: jax.Array, lam: float, *, loss: LossName) -> jax.Array:
     """P(w) over stacked partitions X:(K,n_k,d), y:(K,n_k)."""
-    z = jnp.einsum("knd,d->kn", X, w)
+    z = jnp.einsum("knd,d->kn", X, w, precision=HIGHEST)
     n = z.size
-    return jnp.sum(phi(loss, z, y)) / n + 0.5 * lam * jnp.vdot(w, w)
+    return (jnp.sum(phi(loss, z, y)) / n
+            + 0.5 * lam * jnp.vdot(w, w, precision=HIGHEST))
 
 
 @partial(jax.jit, static_argnames=("loss",))
@@ -171,14 +176,15 @@ def dual_objective(alpha: jax.Array, X: jax.Array, y: jax.Array, lam: float, *, 
     """D(alpha) over stacked partitions, alpha:(K,n_k)."""
     n = alpha.size
     w_alpha = primal_from_dual(alpha, X, lam)
-    return jnp.sum(neg_conj(loss, alpha, y)) / n - 0.5 * lam * jnp.vdot(w_alpha, w_alpha)
+    return (jnp.sum(neg_conj(loss, alpha, y)) / n
+            - 0.5 * lam * jnp.vdot(w_alpha, w_alpha, precision=HIGHEST))
 
 
 @jax.jit
 def primal_from_dual(alpha: jax.Array, X: jax.Array, lam: float) -> jax.Array:
     """w(alpha) = (1/(lambda n)) A alpha  (Eq. 5), A = [x_1 .. x_n] in R^{d x n}."""
     n = alpha.size
-    return jnp.einsum("knd,kn->d", X, alpha) / (lam * n)
+    return jnp.einsum("knd,kn->d", X, alpha, precision=HIGHEST) / (lam * n)
 
 
 @partial(jax.jit, static_argnames=("loss",))

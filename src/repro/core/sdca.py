@@ -34,7 +34,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objectives import LossName, _HINGE_SMOOTHING
+from repro.core.objectives import HIGHEST, LossName, _HINGE_SMOOTHING
 
 
 class LocalSolveResult(NamedTuple):
@@ -90,7 +90,8 @@ def solve_subproblem_indices(
         dalpha, v = carry
         x_i = X[i]
         a_i = alpha[i] + dalpha[i]
-        z_i = jnp.dot(w_eff, x_i) + sigma_prime * jnp.dot(v, x_i)
+        z_i = (jnp.dot(w_eff, x_i, precision=HIGHEST)
+               + sigma_prime * jnp.dot(v, x_i, precision=HIGHEST))
         q_i = sigma_prime * norms_sq[i] / (lam * n_global)
         delta = _coordinate_delta(loss, a_i, z_i, y[i], q_i)
         dalpha = dalpha.at[i].add(delta)
@@ -158,7 +159,7 @@ def sdca_reference(
     def body(carry, i):
         alpha, w = carry
         x_i = X[i]
-        z_i = jnp.dot(w, x_i)
+        z_i = jnp.dot(w, x_i, precision=HIGHEST)
         q_i = norms_sq[i] / (lam * n)
         delta = _coordinate_delta(loss, alpha[i], z_i, y[i], q_i)
         alpha = alpha.at[i].add(delta)

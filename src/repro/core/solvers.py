@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.objectives import LossName
+from repro.core.objectives import HIGHEST, LossName
 from repro.core.sdca import (LocalSolveResult, solve_subproblem,
                              solve_subproblem_indices)
 
@@ -80,7 +80,8 @@ def solve_subproblem_accelerated(
         # extrapolate in the dual
         momentum = beta * (dalpha - dalpha_prev)
         da_y = dalpha + momentum
-        v_y = v + X.T @ momentum / (lam * n_global)
+        v_y = v + jnp.matmul(X.T, momentum, precision=HIGHEST) / (
+            lam * n_global)
         idx = jax.random.randint(k, (inner,), 0, n_k, dtype=jnp.int32)
         res = solve_subproblem_indices(
             w_eff + sigma_prime * v_y, alpha + da_y, X, y, norms_sq, lam,

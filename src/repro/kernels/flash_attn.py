@@ -91,7 +91,7 @@ def flash_attention_fwd_pallas(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Returns (B, S, KV, G, hd). Pads S to block multiples internally."""
     B, S, KV, G, hd = q.shape

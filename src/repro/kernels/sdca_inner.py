@@ -1,9 +1,9 @@
 """Pallas TPU kernel for the worker's SDCA inner loop (Algorithm 2, line 4).
 
 Runs H sequential ridge-SDCA coordinate steps on one worker partition with the
-whole working set resident in VMEM:
+whole working set resident in on-chip memory:
 
-    state: dalpha (n_k,), v (d,)            [kept in the loop carry]
+    state: dalpha (n_k,) in SMEM, v (d,) in VMEM   [updated through refs]
     step : i = idx[h]
            z     = (w_eff + sigma' v) . x_i
            delta = (y_i - a_i - z) / (1 + sigma' ||x_i||^2 / (lambda n))
@@ -15,13 +15,16 @@ TPU adaptation vs. a CPU/GPU implementation is residency: the (n_k, d) data
 tile, w_eff and the evolving v never leave VMEM during the H steps, so HBM
 traffic is one read of the partition + O(n_k + d) instead of H * O(d).
 
-Grid = workers (one program per partition, matching the paper's K workers);
-the coordinate visit order is supplied via scalar prefetch so the index stream
-is available in SMEM before the program body runs.
+Grid = workers (one program per partition, matching the paper's K workers).
+Per-coordinate values -- the visit order, alpha, y, ||x_i||^2 and dalpha --
+are (1, 1, .) SMEM blocks read and written as scalars; the partition, w_eff
+and v are (1, ., d) VMEM blocks, and a step reads its row with a dynamic
+sublane slice.
 
-Capacity contract: n_k * d * 4B + 2*d*4B must fit VMEM (~16 MB/core), i.e.
-n_k * d <~ 4M. ``ops.sdca_epoch`` falls back to the jnp path beyond that.
-Ridge only (the paper's experiments); other losses use the jnp path.
+Capacity contract: ``ops.sdca_epoch`` checks the VMEM and SMEM budgets
+(double-buffered blocks) and raises for a shape over either.  Ridge only (the
+paper's experiments); :func:`repro.kernels.ref.sdca_inner_ref` covers the
+rest.
 """
 
 from __future__ import annotations
@@ -34,44 +37,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _sdca_kernel(idx_row,  # (H,) int32 visit order for this worker (SMEM-read)
-                 w_ref,  # (1, d) VMEM
-                 alpha_ref,  # (1, n_k) VMEM
-                 x_ref,  # (1, n_k, d) VMEM
-                 y_ref,  # (1, n_k) VMEM
-                 norms_ref,  # (1, n_k) VMEM
-                 scal_ref,  # SMEM: [lam_n, sigma_prime]
-                 dalpha_ref,  # out (1, n_k)
-                 v_ref,  # out (1, d)
+def _sdca_kernel(scal_ref,  # SMEM (2,): [lam * n, sigma_prime]
+                 idx_ref,  # SMEM (1, 1, H) int32 visit order
+                 alpha_ref,  # SMEM (1, 1, n_k)
+                 y_ref,  # SMEM (1, 1, n_k)
+                 norms_ref,  # SMEM (1, 1, n_k)
+                 w_ref,  # VMEM (1, 1, d) w_eff
+                 x_ref,  # VMEM (1, n_k, d) the partition
+                 dalpha_ref,  # out SMEM (1, 1, n_k)
+                 v_ref,  # out VMEM (1, 1, d)
                  ):
-    h_steps = idx_row.shape[0]
+    n_k = x_ref.shape[1]
+    h_steps = idx_ref.shape[2]
     lam_n = scal_ref[0]
     sigma_p = scal_ref[1]
 
-    w_eff = w_ref[0, :]
-    alpha = alpha_ref[0, :]
-    y = y_ref[0, :]
-    norms = norms_ref[0, :]
+    def zero(i, carry):
+        dalpha_ref[0, 0, i] = jnp.float32(0.0)
+        return carry
+
+    jax.lax.fori_loop(0, n_k, zero, 0)
+    v_ref[...] = jnp.zeros_like(v_ref)
+    w_eff = w_ref[0]  # (1, d)
 
     def body(h, carry):
-        dalpha, v = carry
-        i = idx_row[h]
-        # All-slice index tuple: a bare scalar 0 here breaks the JAX 0.4.x
-        # interpret-mode discharge rule (int has no .shape).
-        x_i = pl.load(x_ref, (pl.ds(0, 1), pl.ds(i, 1), slice(None)))[0, 0]  # (d,)
-        a_i = alpha[i] + dalpha[i]
-        z_i = jnp.dot(w_eff, x_i) + sigma_p * jnp.dot(v, x_i)
-        q_i = sigma_p * norms[i] / lam_n
-        delta = (y[i] - a_i - z_i) / (1.0 + q_i)
-        dalpha = dalpha.at[i].add(delta)
-        v = v + (delta / lam_n) * x_i
-        return dalpha, v
+        i = idx_ref[0, 0, h]
+        x_i = x_ref[0, pl.ds(i, 1), :]  # (1, d)
+        a_i = alpha_ref[0, 0, i] + dalpha_ref[0, 0, i]
+        z_i = jnp.sum(w_eff * x_i) + sigma_p * jnp.sum(v_ref[0] * x_i)
+        q_i = sigma_p * norms_ref[0, 0, i] / lam_n
+        delta = (y_ref[0, 0, i] - a_i - z_i) / (1.0 + q_i)
+        dalpha_ref[0, 0, i] = dalpha_ref[0, 0, i] + delta
+        v_ref[0] = v_ref[0] + (delta / lam_n) * x_i
+        return carry
 
-    dalpha0 = jnp.zeros(alpha.shape, alpha.dtype)
-    v0 = jnp.zeros(w_eff.shape, w_eff.dtype)
-    dalpha, v = jax.lax.fori_loop(0, h_steps, body, (dalpha0, v0))
-    dalpha_ref[0, :] = dalpha
-    v_ref[0, :] = v
+    jax.lax.fori_loop(0, h_steps, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,43 +86,33 @@ def sdca_inner_pallas(
     sigma_prime: float,
     idx: jax.Array,  # (K, H) int32 visit order per worker
     *,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """All-K-workers SDCA epoch; returns (dalpha (K,n_k), v (K,d))."""
     K, n_k, d = X.shape
     H = idx.shape[1]
     scal = jnp.array([lam * n_global, sigma_prime], jnp.float32)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    def scalars(width):  # one worker's (1, 1, width) row of a (K, 1, width)
+        return pl.BlockSpec((1, 1, width), lambda k: (k, 0, 0),
+                            memory_space=pltpu.SMEM)
+
+    row = pl.BlockSpec((1, 1, d), lambda k: (k, 0, 0))
+    dalpha, v = pl.pallas_call(
+        _sdca_kernel,
         grid=(K,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda k, idx: (k, 0)),
-            pl.BlockSpec((1, n_k), lambda k, idx: (k, 0)),
-            pl.BlockSpec((1, n_k, d), lambda k, idx: (k, 0, 0)),
-            pl.BlockSpec((1, n_k), lambda k, idx: (k, 0)),
-            pl.BlockSpec((1, n_k), lambda k, idx: (k, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM), scalars(H),
+            scalars(n_k), scalars(n_k), scalars(n_k), row,
+            pl.BlockSpec((1, n_k, d), lambda k: (k, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, n_k), lambda k, idx: (k, 0)),
-            pl.BlockSpec((1, d), lambda k, idx: (k, 0)),
-        ],
-    )
-
-    def kernel(idx_ref, w_ref, alpha_ref, x3_ref, y_ref, norms_ref, scal_ref,
-               dalpha_ref, v_ref):
-        k = pl.program_id(0)
-        _sdca_kernel(idx_ref[k], w_ref, alpha_ref, x3_ref, y_ref, norms_ref,
-                     scal_ref, dalpha_ref, v_ref)
-
-    dalpha, v = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
+        out_specs=[scalars(n_k), row],
         out_shape=[
-            jax.ShapeDtypeStruct((K, n_k), X.dtype),
-            jax.ShapeDtypeStruct((K, d), X.dtype),
+            jax.ShapeDtypeStruct((K, 1, n_k), X.dtype),
+            jax.ShapeDtypeStruct((K, 1, d), X.dtype),
         ],
         interpret=interpret,
-    )(idx, w_eff, alpha, X, y, norms_sq, scal)
-    return dalpha, v
+    )(scal, idx.reshape(K, 1, H), alpha.reshape(K, 1, n_k),
+      y.reshape(K, 1, n_k), norms_sq.reshape(K, 1, n_k),
+      w_eff.reshape(K, 1, d), X)
+    return dalpha.reshape(K, n_k), v.reshape(K, d)

@@ -56,18 +56,44 @@ def _bucket_edges(hi: jax.Array, lo: jax.Array) -> jax.Array:
 
 
 def _histogram_kernel(x_ref, edges_ref, counts_ref):
-    """counts[j] += #{ |tile| >= edges[j] } ; counts block is revisited."""
+    """counts[j] += #{ |tile| >= edges[j] } ; counts block is revisited.
+
+    ``edges_ref`` is a (NUM_BUCKETS, 1) column, so every comparison is a
+    2-D (NUM_BUCKETS, LANE) broadcast of one tile row against the ladder;
+    the tile is never flattened (the TPU lowers no 1-D relayout of it).
+    """
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    mag = jnp.abs(x_ref[...].astype(jnp.float32))  # (SUBLANE, LANE)
-    edges = edges_ref[...]  # (1, NUM_BUCKETS)
-    # (NUM_BUCKETS, SUBLANE*LANE) comparison, reduced over elements.
-    ge = mag.reshape(1, -1) >= edges.reshape(NUM_BUCKETS, 1)
-    counts_ref[...] += jnp.sum(ge, axis=1, dtype=jnp.int32).reshape(1, NUM_BUCKETS)
+    mag = jnp.abs(x_ref[...])  # (SUBLANE, LANE)
+    edges = edges_ref[...]  # (NUM_BUCKETS, 1)
+    acc = jnp.zeros((NUM_BUCKETS, LANE), jnp.int32)
+    for s in range(SUBLANE):
+        acc += (mag[s:s + 1, :] >= edges).astype(jnp.int32)
+    counts_ref[...] += jnp.sum(acc, axis=1, keepdims=True)
+
+
+def _exclusive_prefix_count(flags: jax.Array) -> jax.Array:
+    """Row-major exclusive prefix count of a 0/1 (SUBLANE, LANE) f32 tile.
+
+    Two small matmuls against strictly triangular 0/1 matrices: the prefix
+    within each row, plus the total of all earlier rows.  Every operand is
+    an integer below 2**8, so the products are exact at any MXU precision.
+    """
+    lane_i = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+    lane_j = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1)
+    upper = (lane_i < lane_j).astype(jnp.float32)
+    within_row = jnp.dot(flags, upper, preferred_element_type=jnp.float32)
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, SUBLANE), 0)
+    row_j = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, SUBLANE), 1)
+    lower = (row_j < row_i).astype(jnp.float32)
+    row_totals = jnp.sum(flags, axis=1, keepdims=True)  # (SUBLANE, 1)
+    earlier_rows = jnp.dot(lower, jnp.broadcast_to(row_totals, (SUBLANE, LANE)),
+                           preferred_element_type=jnp.float32)
+    return (within_row + earlier_rows).astype(jnp.int32)
 
 
 def _emit_kernel(x_ref, thresh_ref, sent_ref, resid_ref, mask_ref, band_used_ref):
@@ -83,7 +109,7 @@ def _emit_kernel(x_ref, thresh_ref, sent_ref, resid_ref, mask_ref, band_used_ref
         band_used_ref[0] = 0
 
     x = x_ref[...]
-    mag = jnp.abs(x.astype(jnp.float32))
+    mag = jnp.abs(x)
     t_lo = thresh_ref[0]
     t_hi = thresh_ref[1]
     quota = thresh_ref[2].astype(jnp.int32)
@@ -92,17 +118,16 @@ def _emit_kernel(x_ref, thresh_ref, sent_ref, resid_ref, mask_ref, band_used_ref
     band = (mag >= t_lo) & (mag < t_hi)
 
     # Admit band elements in index order while quota lasts. The tile is a
-    # contiguous row-major chunk, so flattening preserves index order.
-    band_flat = band.reshape(-1)
-    prefix_excl = jnp.cumsum(band_flat.astype(jnp.int32)) - band_flat.astype(jnp.int32)
+    # contiguous row-major chunk, so row-major order is index order.
+    band_f = band.astype(jnp.float32)
     already = band_used_ref[0]
-    admit = band_flat & (already + prefix_excl < quota)
-    band_used_ref[0] = already + jnp.sum(band_flat.astype(jnp.int32))
+    admit = band & (already + _exclusive_prefix_count(band_f) < quota)
+    band_used_ref[0] = already + jnp.sum(band_f).astype(jnp.int32)
 
-    keep = strong | admit.reshape(strong.shape)
+    keep = strong | admit
     sent_ref[...] = jnp.where(keep, x, jnp.zeros_like(x))
     resid_ref[...] = jnp.where(keep, jnp.zeros_like(x), x)
-    mask_ref[...] = keep
+    mask_ref[...] = keep.astype(jnp.int32)
 
 
 def _pad_to_tiles(x: jax.Array) -> tuple[jax.Array, int]:
@@ -119,13 +144,13 @@ def _histogram(x2d: jax.Array, edges: jax.Array, n_tiles: int, interpret: bool) 
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((1, NUM_BUCKETS), lambda i: (0, 0)),
+            pl.BlockSpec((NUM_BUCKETS, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, NUM_BUCKETS), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, NUM_BUCKETS), jnp.int32),
+        out_specs=pl.BlockSpec((NUM_BUCKETS, 1), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((NUM_BUCKETS, 1), jnp.int32),
         interpret=interpret,
-    )(x2d, edges.reshape(1, NUM_BUCKETS))
-    return counts[0]
+    )(x2d, edges.reshape(NUM_BUCKETS, 1))
+    return counts[:, 0]
 
 
 def _select_band(counts: jax.Array, edges: jax.Array, k: int) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -146,17 +171,19 @@ def _select_band(counts: jax.Array, edges: jax.Array, k: int) -> tuple[jax.Array
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret", "refine"))
-def topk_filter_pallas(dw: jax.Array, k: int, *, interpret: bool = True,
+def topk_filter_pallas(dw: jax.Array, k: int, *, interpret: bool = False,
                        refine: bool = True):
     """Kernel-backed message filter. Returns (sent, residual, mask).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on a real TPU pass interpret=False.
+    The kernels run on float32 (8, 128) tiles; narrower inputs are widened
+    on the way in and narrowed on the way out, which is exact, so
+    ``sent + residual == dw`` still holds bitwise.  ``interpret=True`` runs
+    the kernel bodies through the Pallas interpreter (the CPU tests).
     """
     d = dw.shape[0]
-    x2d, n_tiles = _pad_to_tiles(dw)
+    x2d, n_tiles = _pad_to_tiles(dw.astype(jnp.float32))
 
-    mag_max = jnp.max(jnp.abs(dw)).astype(jnp.float32)
+    mag_max = jnp.max(jnp.abs(x2d))
     edges = _bucket_edges(mag_max, mag_max * FLOOR)
     counts = _histogram(x2d, edges, n_tiles, interpret)
     t_lo, t_hi, count_hi = _select_band(counts, edges, k)
@@ -173,26 +200,23 @@ def topk_filter_pallas(dw: jax.Array, k: int, *, interpret: bool = True,
     quota = jnp.maximum(k - count_hi, 0).astype(jnp.float32)
     thresh = jnp.stack([t_lo, jnp.where(jnp.isinf(t_hi), jnp.float32(3.4e38), t_hi), quota])
 
+    tile = pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0))
     sent2d, resid2d, mask2d = pl.pallas_call(
         _emit_kernel,
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
-        ],
+        in_specs=[tile, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=[tile, tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct(x2d.shape, dw.dtype),
-            jax.ShapeDtypeStruct(x2d.shape, dw.dtype),
-            jax.ShapeDtypeStruct(x2d.shape, jnp.bool_),
+            jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
+            jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
+            jax.ShapeDtypeStruct(x2d.shape, jnp.int32),
         ],
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
     )(x2d, thresh)
 
-    flat = lambda a: a.reshape(-1)[:d]
-    return flat(sent2d), flat(resid2d), flat(mask2d)
+    def flat(a, dtype):
+        return a.reshape(-1)[:d].astype(dtype)
+
+    return (flat(sent2d, dw.dtype), flat(resid2d, dw.dtype),
+            flat(mask2d, jnp.bool_))

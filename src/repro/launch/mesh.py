@@ -8,21 +8,10 @@ import jax
 from jax.sharding import Mesh
 
 
-def _axis_type_kwargs(num_axes: int) -> dict:
-    """``axis_types=`` kwarg when this JAX has it, empty dict otherwise.
-
-    ``jax.sharding.AxisType`` only exists from JAX 0.5; on 0.4.x every mesh
-    axis is implicitly Auto, so omitting the kwarg is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """Version-safe ``jax.make_mesh`` with all axes Auto-typed."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """``jax.make_mesh`` with all axes Auto-typed."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -21,7 +21,10 @@
 ``python -m repro serve --replica-of <cluster-dir>`` runs a **cluster
 replica** instead of binding HTTP: the process joins the shared-directory
 serve cluster of :mod:`repro.serve.cluster` and executes jobs from its
-``jobs/`` queue under lease ownership (docs/fault-tolerance.md).
+``jobs/`` queue under lease ownership (docs/fault-tolerance.md).  A chip
+belongs to one process, so a replica whose TPU fails to initialize (another
+process holds it) exits at start with JAX's reason instead of running on
+the CPU; ``JAX_PLATFORMS=cpu`` runs a replica on the CPU by choice.
 
 **Error contract** (the ``ERROR_STATUS`` table): every failed request gets a
 structured JSON body ``{"error_type": <class name>, "message": str,
@@ -178,6 +181,24 @@ def serve_http(service: ExperimentService, host: str = "127.0.0.1",
     return ThreadingHTTPServer((host, port), make_handler(service))
 
 
+def require_accelerator() -> None:
+    """Exit with the reason when an installed TPU backend failed to
+    initialize, typically because another process holds the chip.
+
+    JAX would otherwise fall back to the CPU without a word.  An explicit
+    ``JAX_PLATFORMS`` is the caller's choice and is left alone.
+    """
+    import jax
+
+    if jax.config.jax_platforms:
+        return
+    try:
+        jax.devices("tpu")
+    except RuntimeError as e:
+        if "failed to initialize" in str(e):
+            raise SystemExit(f"replica cannot use its TPU: {e}") from None
+
+
 def main(argv: list[str] | None = None) -> None:
     """CLI entry point: ``python -m repro serve``."""
     import argparse
@@ -244,6 +265,7 @@ def main(argv: list[str] | None = None) -> None:
 
         from repro.serve.cluster import ClusterReplica
 
+        require_accelerator()
         replica_id = args.replica_id or f"replica-{_os.getpid()}"
         replica = ClusterReplica(
             args.replica_of, replica_id, fault=fault,

@@ -8,7 +8,7 @@ def timings(n):
 
 
 def guarded(n):
-    from jax.experimental import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return jnp.zeros((n,), jnp.float64)  # ok: enable_x64 in scope

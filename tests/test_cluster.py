@@ -22,6 +22,7 @@ Pins the cross-process robustness contract on top of the PR-9 serve stack:
   replicas, a real ``SIGKILL``, and a peer takeover observed end to end.
 """
 
+import importlib.util
 import json
 import os
 import pathlib
@@ -549,6 +550,23 @@ class TestResultCache:
 
 
 class TestSubprocessCluster:
+    @pytest.mark.skipif(importlib.util.find_spec("libtpu") is None,
+                        reason="no TPU plugin installed")
+    def test_replica_without_its_tpu_fails_at_start(self, tmp_path):
+        """With the TPU plugin installed but no usable chip (none here, or
+        one held by another process), a replica exits at start with JAX's
+        reason instead of quietly serving from the CPU."""
+        env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+        env["PYTHONPATH"] = (str(REPO / "src") + os.pathsep
+                             + env.get("PYTHONPATH", ""))
+        env["TPU_LOG_DIR"] = "disabled"
+        out = subprocess.run(
+            [sys.executable, "-m", "repro", "serve", "--replica-of",
+             str(tmp_path), "--replica-id", "r0"],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
+        assert out.returncode != 0
+        assert "replica cannot use its TPU" in out.stderr
+
     def _spawn(self, cluster_dir, replica_id, log, fault=None, params=None):
         env = dict(os.environ)
         env["PYTHONPATH"] = (str(REPO / "src") + os.pathsep

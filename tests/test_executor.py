@@ -245,6 +245,28 @@ def test_lag_scan_round_count_scales_free_of_dispatches(small_problem,
     assert dispatch_counter()["lag_calls"] == 1
 
 
+def test_lag_accounts_replays_the_clock_and_checks_the_pop_order():
+    """The host replay of a lag run's accounting: IEEE float64 clocks from
+    the device's decisions, and a loud failure if the device popped the
+    workers in another order than the float64 arrival times give."""
+    needs, T, lat, bw = np.asarray([1]), 2, 1e-3, 1e3
+    durations = np.asarray([[1.0, 2.0], [0.5, 0.5]])
+    lf = np.ones(2)
+    init_bytes = np.asarray([8, 8])
+    reply_bytes, launch_bytes = np.asarray([[16, 0]]), np.asarray([[8, 0]])
+    (acct,) = executor.lag_accounts(needs, T, durations, lf, lat, bw,
+                                    init_bytes, np.asarray([[0, 1]]),
+                                    reply_bytes, launch_bytes)
+    assert acct.sim_time == 0.0 + 1.0 + (lat + 8 / bw)  # worker 0 first
+    assert (acct.arrivals, acct.bytes_up, acct.bytes_down) == (1, 24, 16)
+    assert acct.comm_time == (lat + 8 / bw) + (lat + 8 / bw) \
+        + (lat + 16 / bw) + (lat + 8 / bw)
+    with pytest.raises(RuntimeError, match="popped workers"):
+        executor.lag_accounts(needs, T, durations, lf, lat, bw, init_bytes,
+                              np.asarray([[1, 0]]), reply_bytes,
+                              launch_bytes)
+
+
 # ---------------------------------------------------------------------------
 # Deferred-eval bucketing.
 # ---------------------------------------------------------------------------

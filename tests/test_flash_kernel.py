@@ -20,7 +20,7 @@ def test_pallas_flash_matches_jnp(B, S, KV, G, hd, causal, blk):
     k = jnp.asarray(rng.standard_normal((B, S, KV, hd)).astype(np.float32)) * 0.4
     v = jnp.asarray(rng.standard_normal((B, S, KV, hd)).astype(np.float32))
     out_k = flash_attention_fwd_pallas(q, k, v, causal=causal, block_q=blk,
-                                       block_k=blk)
+                                       block_k=blk, interpret=True)
     out_r = flash_attention(q * (hd**-0.5), k, v,
                             FlashSpec(causal, None, blk, blk, None))
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
@@ -36,7 +36,8 @@ def test_pallas_flash_bf16():
          * 0.4).astype(jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((B, S, KV, hd)).astype(np.float32)
                     ).astype(jnp.bfloat16)
-    out_k = flash_attention_fwd_pallas(q, k, v, block_q=32, block_k=32)
+    out_k = flash_attention_fwd_pallas(q, k, v, block_q=32, block_k=32,
+                                       interpret=True)
     out_r = flash_attention((q.astype(jnp.float32) * hd**-0.5).astype(jnp.bfloat16),
                             k, v, FlashSpec(True, None, 32, 32, None))
     np.testing.assert_allclose(np.asarray(out_k, np.float32),

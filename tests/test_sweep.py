@@ -155,6 +155,26 @@ def test_run_sweep_rejects_empty_axes(small_problem):
             api.run_sweep(small_problem, m, _cluster(), num_outer=1, **kw)
 
 
+@pytest.mark.parametrize("method", ["lockstep", "lag"])
+def test_lower_sweep_sizes_the_dispatch_without_running_it(
+        small_problem, dispatch_counter, method):
+    """lower_sweep hands back the very computation run_sweep dispatches
+    (same jit, same cache key), compiled on demand and never run."""
+    m = baselines.cocoa_plus(K, H=16) if method == "lockstep" else _lag()
+    kw = dict(num_outer=1, seeds=(0, 1), gammas=(1.0, 0.5),
+              delays=SWEEP_DELAYS, eval_every=1)
+    lowered = api.lower_sweep(small_problem, m, _cluster(), **kw)
+    mem = lowered.compile().memory_analysis()
+    assert mem.argument_size_in_bytes >= small_problem.X.nbytes
+    prefix = "sweep" if method == "lockstep" else "sweep_lag"
+    assert dispatch_counter()[f"{prefix}_calls"] == 0  # nothing dispatched
+    assert dispatch_counter()[f"{prefix}_traces"] == 1
+    api.run_sweep(small_problem, m, _cluster(), **kw)
+    # The dispatch reuses the trace: it is the same jitted computation.
+    assert dispatch_counter()[f"{prefix}_calls"] == 1
+    assert dispatch_counter()[f"{prefix}_traces"] == 1
+
+
 # ---------------------------------------------------------------------------
 # Grid-shape retrace contract (the pow2 cell-padding satellite).
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ top-k on tie-free inputs:
   construction; included so every case exercises the shared contract);
 * ``core.exchange.threshold_for_topk`` -- two-round histogram threshold used
   by the deep-net exchange layer;
-* ``kernels.ops.topk_filter``      -- the Pallas histogram-select kernel.
+* ``kernels.ops.topk_filter``      -- the Pallas histogram-select kernel
+  (through the Pallas interpreter here).
 
 The histogram implementations resolve magnitudes to one refined bucket
 (~0.4% ratio), so the shared cases use ladder magnitudes with pairwise gaps
@@ -75,7 +76,7 @@ def test_threshold_for_topk_matches_exact(d, k, seed, dtype):
 @pytest.mark.parametrize("d,k,seed", CASES, ids=_IDS)
 def test_kernel_topk_filter_matches_exact(d, k, seed, dtype):
     x = _tie_free_input(d, seed, dtype)
-    sent, resid, mask = ops.topk_filter(x, k)
+    sent, resid, mask = ops.topk_filter(x, k, interpret=True)
     kept = set(np.flatnonzero(np.asarray(mask)).tolist())
     assert kept == _exact_topk_indices(x, k)
     # conservation is part of the shared contract
