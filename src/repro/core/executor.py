@@ -719,11 +719,13 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
         w_server = s["w_server"] + gamma * total
         dw_tilde = s["dw_tilde"] + gamma * total[None, :]
 
-        snap_rows = s["alpha"][perm]  # == each message's dual snapshot
-        app_rows = s["applied"][perm]
-        mask = (sel & app_rows)[:, None]
-        alpha_applied = s["alpha_applied"].at[perm].set(
-            jnp.where(mask, snap_rows, s["alpha_applied"][perm]))
+        # Each arrived upload's dual snapshot (its worker's ``alpha`` row)
+        # becomes server-visible.  Selected per worker: XLA:TPU returned the
+        # operand unchanged for the rank-space form
+        # ``a.at[perm].set(where(m, b[perm], a[perm]))``.
+        _, rank = jax.lax.sort((perm, iota), num_keys=1)
+        mask = ((rank < need) & s["applied"])[:, None]
+        alpha_applied = jnp.where(mask, s["alpha"], s["alpha_applied"])
         replies = dw_tilde[perm]
         reply_nnz = jnp.sum(replies != 0, axis=1)
         reply_sq = jnp.sum(replies * replies, axis=1)
