@@ -13,6 +13,10 @@ contracts over the source AST -- no imports, no tracing, no device:
   *reachable from traced entry points* (``jax.jit`` / ``lax.scan`` /
   ``shard_map`` / ``pallas_call`` consumers).  Host-side-by-design code is
   simply not reachable; the rest is a dispatch stall on the hot path.
+* ``traced-span``        -- ``tracing.span`` (a profiler
+  ``TraceAnnotation``) in the same traced reach: traced code runs once at
+  trace time, so a host span there measures nothing; device regions are
+  named with ``jax.named_scope``.
 * ``jit-donation``       -- a ``jax.jit`` whose wrapped function takes
   carry-style state arguments must declare ``donate_argnums`` (the engine's
   fused rounds all donate; a new hot jit that forgets doubles its HBM
@@ -574,6 +578,35 @@ class TracedHostSyncRule(Rule):
                     out.append(module.finding(
                         self.rule_name, stmt.lineno,
                         f"{msg} [traced via {fn.qualname}]"))
+        return out
+
+
+@register_rule("traced-span")
+class TracedSpanRule(Rule):
+    """No host span inside traced code: it runs once, at trace time."""
+
+    description = ("flags repro.core.tracing.span / jax.profiler "
+                   "TraceAnnotation inside functions reachable from jax.jit / "
+                   "lax.scan / shard_map / pallas_call call sites: traced "
+                   "code runs once at trace time, so the span measures "
+                   "nothing (name the device region with jax.named_scope)")
+
+    _SPANS = {"repro.core.tracing.span", "jax.profiler.TraceAnnotation",
+              "jax.profiler.StepTraceAnnotation"}
+
+    def check(self, module, project):
+        out = []
+        for fn in project.traced_functions(module):
+            for stmt in fn.own_statements():
+                if not isinstance(stmt, ast.Call):
+                    continue
+                canon = module.canonical(stmt.func)
+                if canon in self._SPANS:
+                    out.append(module.finding(
+                        self.rule_name, stmt.lineno,
+                        f"{canon}(...) in traced code records one span at "
+                        f"trace time, none per run; use jax.named_scope "
+                        f"[traced via {fn.qualname}]"))
         return out
 
 

@@ -31,6 +31,7 @@ from repro.core import engine, objectives
 from repro.core import compress as compress_lib
 from repro.core import executor as executor_lib
 from repro.core import solvers as solvers_lib
+from repro.core import tracing
 from repro.core.acpd import MethodConfig, RunRecord, RunResult
 from repro.core.simulate import ClusterModel
 
@@ -169,8 +170,9 @@ class Session:
         # __init__ carries the per-protocol validation (cocoa's gamma bound,
         # lag_window >= 1, async's B=1) and the event loop's state; the scan
         # backend re-derives its own state from the same (spec, seed).
-        self.proto = engine.get_protocol(method.protocol)(
-            problem, method, cluster, seed=seed)
+        with tracing.span("repro.session.init"):
+            self.proto = engine.get_protocol(method.protocol)(
+                problem, method, cluster, seed=seed)
         ok, why = executor_lib.scan_supported(
             method, cluster, eval_mode=eval_mode, target_gap=target_gap,
             time_budget=time_budget)
@@ -225,6 +227,18 @@ class Session:
 
     # -- the canonical loop ------------------------------------------------
 
+    def _evaluate(self, proto, iteration: int, snaps: list):
+        """The eval boundary after round ``iteration``: a streamed
+        certificate (returned, to be yielded) or a deferred snapshot
+        (appended to ``snaps``; returns None)."""
+        with tracing.span("repro.certificate", round=iteration,
+                          session=self.seed):
+            snap = proto.snapshot(iteration)
+            if self.eval_mode != "stream":
+                snaps.append(snap)
+                return None
+            return self._eval_stream(snap)
+
     def _eval_stream(self, snap) -> EvalEvent:
         cert = objectives.gap_certificate(self.problem, snap.alpha, w=snap.w)
         return EvalEvent(
@@ -240,8 +254,13 @@ class Session:
             return
         proto = self.proto
         queue: list[engine.Message] = []
-        for msg in proto.initial_messages():
-            heapq.heappush(queue, msg)
+        # Round 0: the first launch of every worker.  No span stays open
+        # across a yield: the consumer's code runs in between.
+        with tracing.span("repro.round", round=0, session=self.seed):
+            first = proto.initial_messages()
+            with tracing.span("repro.engine.queue"):
+                for msg in first:
+                    heapq.heappush(queue, msg)
 
         snaps = []  # deferred-eval snapshots ("batched"/"replay")
         records: list[RunRecord] = []  # streamed records ("stream")
@@ -250,33 +269,38 @@ class Session:
         reason = "completed"
 
         for r in range(proto.num_rounds(self.num_outer)):
-            need = proto.arrivals_needed(r)
-            arrived = [heapq.heappop(queue) for _ in range(need)]
-            for msg in proto.process_round(r, arrived):
-                heapq.heappush(queue, msg)
-            iteration += 1
-
-            yield RoundEvent(
-                iteration=iteration, sim_time=proto.sim_time,
-                arrivals=len(arrived), bytes_up=proto.bytes_up,
-                bytes_down=proto.bytes_down, compute_time=proto.compute_time,
-                comm_time=proto.comm_time)
+            with tracing.span("repro.round", round=iteration + 1,
+                              session=self.seed):
+                with tracing.span("repro.engine.queue"):
+                    need = proto.arrivals_needed(r)
+                    arrived = [heapq.heappop(queue) for _ in range(need)]
+                launched = proto.process_round(r, arrived)
+                with tracing.span("repro.engine.queue"):
+                    for msg in launched:
+                        heapq.heappush(queue, msg)
+                iteration += 1
+                tracing.STATS["event_rounds"] += 1
+                tracing.STATS["event_arrivals"] += len(arrived)
+                round_event = RoundEvent(
+                    iteration=iteration, sim_time=proto.sim_time,
+                    arrivals=len(arrived), bytes_up=proto.bytes_up,
+                    bytes_down=proto.bytes_down,
+                    compute_time=proto.compute_time,
+                    comm_time=proto.comm_time)
+            yield round_event
             if proto.is_sync_round(r):
                 yield SyncEvent(iteration=iteration, sim_time=proto.sim_time)
 
             evaluated = iteration % self.eval_every == 0
             if evaluated:
-                snap = proto.snapshot(iteration)
-                if streaming:
-                    ev = self._eval_stream(snap)
+                ev = self._evaluate(proto, iteration, snaps)
+                if ev is not None:
                     records.append(ev.to_record())
                     yield ev
                     if (self.target_gap is not None
                             and ev.gap <= self.target_gap):
                         reason = "target_gap"
                         break
-                else:
-                    snaps.append(snap)
 
             if (self.time_budget is not None
                     and proto.sim_time >= self.time_budget):
@@ -284,13 +308,10 @@ class Session:
                 if not evaluated:
                     # Terminal certificate so the result reflects the state
                     # at the stop point.
-                    snap = proto.snapshot(iteration)
-                    if streaming:
-                        ev = self._eval_stream(snap)
+                    ev = self._evaluate(proto, iteration, snaps)
+                    if ev is not None:
                         records.append(ev.to_record())
                         yield ev
-                    else:
-                        snaps.append(snap)
                 break
 
         if not streaming:

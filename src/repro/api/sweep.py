@@ -70,7 +70,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import compress as compress_lib
-from repro.core import engine, executor, objectives
+from repro.core import engine, executor, objectives, tracing
 from repro.core.acpd import MethodConfig, RunRecord, RunResult
 from repro.core.simulate import ClusterModel
 from repro.launch import mesh as mesh_lib
@@ -516,27 +516,32 @@ def _run_lockstep_sweep(problem, method, variants, cells, *, num_outer,
     # host-side accounting for lockstep runs, so the first delay block's
     # cells run once and every delay variant reuses them.
     block = cells[:len(cells) // len(variants)]
-    fn, args, kw, evals = _lockstep_call(problem, method, block,
-                                         num_outer=num_outer,
-                                         eval_every=eval_every, batch=batch,
-                                         plan=plan)
+    with tracing.span("repro.sweep.prepare"):
+        fn, args, kw, evals = _lockstep_call(problem, method, block,
+                                             num_outer=num_outer,
+                                             eval_every=eval_every,
+                                             batch=batch, plan=plan)
     executor.STATS["sweep_calls"] += 1
-    w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
+    with tracing.span("repro.sweep.dispatch"):
+        w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
     V, S = len(block), len(evals)
-    p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], alphas_eval[:V, :S],
-                                     problem, V, S)
+    with tracing.span("repro.sweep.fetch"):
+        p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S],
+                                         alphas_eval[:V, :S], problem, V, S)
     # Gamma does not move the simulated clock: accounting is per
     # (delay variant, seed).
     out = []
-    for name, cl in variants:
-        accounts = {c.seed: executor.lockstep_accounts(
-            method, cl, d, num_rounds=num_outer, seed=c.seed) for c in block}
-        for v, c in enumerate(block):
-            records = _variant_records(accounts[c.seed], evals, gap,
-                                       gap_srv, p, dv, v)
-            out.append(SweepVariant(c.seed, c.gamma, RunResult(
-                dataclasses.replace(method, gamma=c.gamma), records,
-                np.asarray(w[v]), np.asarray(alpha[v])), delay=name))
+    with tracing.span("repro.sweep.records"):
+        for name, cl in variants:
+            accounts = {c.seed: executor.lockstep_accounts(
+                method, cl, d, num_rounds=num_outer, seed=c.seed)
+                for c in block}
+            for v, c in enumerate(block):
+                records = _variant_records(accounts[c.seed], evals, gap,
+                                           gap_srv, p, dv, v)
+                out.append(SweepVariant(c.seed, c.gamma, RunResult(
+                    dataclasses.replace(method, gamma=c.gamma), records,
+                    np.asarray(w[v]), np.asarray(alpha[v])), delay=name))
     return out
 
 
@@ -632,31 +637,36 @@ def _lag_cells(problem, method, cells, *, num_outer, eval_every, batch,
     mcfgs = [dataclasses.replace(method, gamma=c.gamma,
                                  sigma_prime=c.sigma_prime) for c in cells]
     with jax.enable_x64(True):
-        fn, args, kw, evals, timing = _lag_call(
-            problem, method, cells, num_outer=num_outer,
-            eval_every=eval_every, batch=batch, plan=plan)
+        with tracing.span("repro.sweep.prepare"):
+            fn, args, kw, evals, timing = _lag_call(
+                problem, method, cells, num_outer=num_outer,
+                eval_every=eval_every, batch=batch, plan=plan)
         executor.STATS["sweep_lag_calls"] += 1
-        (w, alpha, alpha_applied, ws_eval, app_eval, init_bytes, order,
-         reply_bytes, launch_bytes) = fn(*args, **kw)
+        with tracing.span("repro.sweep.dispatch"):
+            (w, alpha, alpha_applied, ws_eval, app_eval, init_bytes, order,
+             reply_bytes, launch_bytes) = fn(*args, **kw)
 
     V, S = len(cells), len(evals)
-    p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], app_eval[:V, :S],
-                                     problem, V, S)
-    init_bytes, order, reply_bytes, launch_bytes = (
-        np.asarray(a) for a in (init_bytes, order, reply_bytes,
-                                launch_bytes))
+    with tracing.span("repro.sweep.fetch"):
+        p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], app_eval[:V, :S],
+                                         problem, V, S)
+        init_bytes, order, reply_bytes, launch_bytes = (
+            np.asarray(a) for a in (init_bytes, order, reply_bytes,
+                                    launch_bytes))
     durations, link_factors, lats, bws = timing
     out = []
-    for v, c in enumerate(cells):
-        rounds = executor.lag_accounts(
-            needs, T, durations[v], link_factors[v], float(lats[v]),
-            float(bws[v]), init_bytes[v], order[v], reply_bytes[v],
-            launch_bytes[v])
-        records = _variant_records(rounds, evals, gap, gap_srv, p, dv, v)
-        out.append(SweepVariant(c.seed, c.gamma, RunResult(
-            mcfgs[v], records, np.asarray(w[v]), np.asarray(alpha[v]),
-            alpha_applied=np.asarray(alpha_applied[v])),
-            delay=c.cluster.delay_model, rounds=tuple(rounds)))
+    with tracing.span("repro.sweep.records"):
+        for v, c in enumerate(cells):
+            rounds = executor.lag_accounts(
+                needs, T, durations[v], link_factors[v], float(lats[v]),
+                float(bws[v]), init_bytes[v], order[v], reply_bytes[v],
+                launch_bytes[v])
+            records = _variant_records(rounds, evals, gap, gap_srv, p, dv,
+                                       v)
+            out.append(SweepVariant(c.seed, c.gamma, RunResult(
+                mcfgs[v], records, np.asarray(w[v]), np.asarray(alpha[v]),
+                alpha_applied=np.asarray(alpha_applied[v])),
+                delay=c.cluster.delay_model, rounds=tuple(rounds)))
     return out
 
 
@@ -665,25 +675,30 @@ def _lockstep_cells(problem, method, cells, *, num_outer, eval_every, batch,
     d = problem.X.shape[2]
     mcfgs = [dataclasses.replace(method, gamma=c.gamma,
                                  sigma_prime=c.sigma_prime) for c in cells]
-    fn, args, kw, evals = _lockstep_call(problem, method, cells,
-                                         num_outer=num_outer,
-                                         eval_every=eval_every, batch=batch,
-                                         plan=plan)
+    with tracing.span("repro.sweep.prepare"):
+        fn, args, kw, evals = _lockstep_call(problem, method, cells,
+                                             num_outer=num_outer,
+                                             eval_every=eval_every,
+                                             batch=batch, plan=plan)
     executor.STATS["sweep_calls"] += 1
-    w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
+    with tracing.span("repro.sweep.dispatch"):
+        w, alpha, ws_eval, alphas_eval = fn(*args, **kw)
 
     V, S = len(cells), len(evals)
-    p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S], alphas_eval[:V, :S],
-                                     problem, V, S)
+    with tracing.span("repro.sweep.fetch"):
+        p, dv, gap, gap_srv = _eval_grid(ws_eval[:V, :S],
+                                         alphas_eval[:V, :S], problem, V, S)
     out = []
-    for v, c in enumerate(cells):
-        rounds = executor.lockstep_accounts(mcfgs[v], c.cluster, d,
-                                            num_rounds=num_outer,
-                                            seed=c.seed)
-        records = _variant_records(rounds, evals, gap, gap_srv, p, dv, v)
-        out.append(SweepVariant(c.seed, c.gamma, RunResult(
-            mcfgs[v], records, np.asarray(w[v]), np.asarray(alpha[v])),
-            delay=c.cluster.delay_model, rounds=tuple(rounds)))
+    with tracing.span("repro.sweep.records"):
+        for v, c in enumerate(cells):
+            rounds = executor.lockstep_accounts(mcfgs[v], c.cluster, d,
+                                                num_rounds=num_outer,
+                                                seed=c.seed)
+            records = _variant_records(rounds, evals, gap, gap_srv, p, dv,
+                                       v)
+            out.append(SweepVariant(c.seed, c.gamma, RunResult(
+                mcfgs[v], records, np.asarray(w[v]), np.asarray(alpha[v])),
+                delay=c.cluster.delay_model, rounds=tuple(rounds)))
     return out
 
 

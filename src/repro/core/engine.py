@@ -75,6 +75,7 @@ import numpy as np
 from repro.core import compress as compress_lib
 from repro.core import filter as msg_filter
 from repro.core import objectives
+from repro.core import tracing
 from repro.core.acpd import MethodConfig, RunRecord, RunResult
 from repro.core.sdca import solve_subproblem
 from repro.core.simulate import ClusterModel
@@ -138,6 +139,14 @@ class Message:
         return (self.arrival, self.seq) < (other.arrival, other.seq)
 
 
+def _host_array(x) -> np.ndarray:
+    """A round's one blocking device->host read: counted in
+    ``tracing.STATS["host_syncs"]`` and spanned as ``repro.engine.sync``."""
+    with tracing.span("repro.engine.sync"):
+        tracing.STATS["host_syncs"] += 1
+        return np.asarray(x)
+
+
 @dataclasses.dataclass
 class _Snapshot:
     """Host-side accounting + device state captured at an eval boundary."""
@@ -163,14 +172,16 @@ def _local_round(key, w_local, alpha_k, residual_k, X_k, y_k, norms_k, k, lam,
     both fused worker rounds inline it so the op sequence (and therefore the
     bit-exact trajectory) is defined in exactly one place. ``comp`` is a
     frozen :mod:`repro.core.compress` registry object (static under jit)."""
-    key, sub = jax.random.split(key)
-    w_eff = w_local[k] + gamma * residual_k
-    dalpha, v = solve_subproblem(
-        w_eff, alpha_k, X_k, y_k, norms_k, lam, n, sigma_p, sub,
-        loss=loss, num_steps=num_steps)
-    alpha_new = alpha_k + gamma * dalpha  # Alg. 2 line 5
-    dw = residual_k + v  # line 6
-    sent, new_residual = comp.compress(dw)
+    with jax.named_scope(tracing.SOLVE):
+        key, sub = jax.random.split(key)
+        w_eff = w_local[k] + gamma * residual_k
+        dalpha, v = solve_subproblem(
+            w_eff, alpha_k, X_k, y_k, norms_k, lam, n, sigma_p, sub,
+            loss=loss, num_steps=num_steps)
+        alpha_new = alpha_k + gamma * dalpha  # Alg. 2 line 5
+        dw = residual_k + v  # line 6
+    with jax.named_scope(tracing.FILTER):
+        sent, new_residual = comp.compress(dw)
     return key, alpha_new, new_residual, dw, sent
 
 
@@ -189,6 +200,7 @@ def _worker_rounds_fused(key, w_local, alpha, residual, X, y, norms_sq, idxs,
     dual snapshots and compressed payloads, stacked in arrival order.
     """
 
+    @jax.named_scope(tracing.WORKER_STATE)
     def body(carry, k):
         key, alpha, residual = carry
         key, alpha_k, res_k, _, sent = _local_round(
@@ -221,6 +233,7 @@ def _worker_chunk_rounds_fused(key, w_local, alpha, residual, X, y, norms_sq,
     post-chunk residuals (``(G, C, n_k)`` / ``(G, C, d)``, arrival order).
     """
 
+    @jax.named_scope(tracing.WORKER_STATE)
     def body(carry, k):
         key, alpha, residual = carry
         alpha_k, res_k = alpha[k], residual[k]
@@ -258,19 +271,20 @@ def _server_apply_partial(w_server, dw_tilde, w_local, alpha_applied,
     accruing until their own pass completes.  With one chunk per pass the
     returned values equal :func:`_server_apply_fused` on the same arrivals.
     """
-    total = jnp.zeros_like(w_server)
-    for p in payloads:
-        total = total + p
-    w_server = w_server + gamma * total
-    dw_tilde = dw_tilde + gamma * total[None, :]
-    if snapshots:
-        alpha_applied = alpha_applied.at[snap_idxs].set(
-            jnp.stack(list(snapshots)))
-    replies = dw_tilde[reply_idxs]
-    reply_nnz = jnp.sum(replies != 0, axis=1)
-    reply_sq = jnp.sum(replies * replies, axis=1)
-    w_local = w_local.at[reply_idxs].add(replies)
-    dw_tilde = dw_tilde.at[reply_idxs].set(0.0)
+    with jax.named_scope(tracing.SERVER_APPLY):
+        total = jnp.zeros_like(w_server)
+        for p in payloads:
+            total = total + p
+        w_server = w_server + gamma * total
+        dw_tilde = dw_tilde + gamma * total[None, :]
+        if snapshots:
+            alpha_applied = alpha_applied.at[snap_idxs].set(
+                jnp.stack(list(snapshots)))
+        replies = dw_tilde[reply_idxs]
+        reply_nnz = jnp.sum(replies != 0, axis=1)
+        reply_sq = jnp.sum(replies * replies, axis=1)
+        w_local = w_local.at[reply_idxs].add(replies)
+        dw_tilde = dw_tilde.at[reply_idxs].set(0.0)
     return w_server, dw_tilde, w_local, alpha_applied, reply_nnz, reply_sq
 
 
@@ -322,6 +336,7 @@ def _worker_rounds_lag_fused(key, w_local, alpha, residual, ref_buf, ref_len,
     (all-quiet -> replies ~ 0 -> uploads resume, no starvation).
     """
 
+    @jax.named_scope(tracing.WORKER_STATE)
     def body(carry, k):
         key, alpha, residual = carry
         ref_k = _lag_reference(ref_buf[k], ref_len[k], xi)
@@ -354,20 +369,21 @@ def _server_apply_fused(w_server, dw_tilde, w_local, alpha_applied, idxs,
     in-graph and returned as one small vector -- the only device->host value
     the event loop needs.
     """
-    total = jnp.zeros_like(w_server)
-    for p in payloads:
-        total = total + p
-    w_server = w_server + gamma * total
-    dw_tilde = dw_tilde + gamma * total[None, :]
-    snap = jnp.stack(list(snapshots))
-    mask = apply_mask[:, None]
-    alpha_applied = alpha_applied.at[idxs].set(
-        jnp.where(mask, snap, alpha_applied[idxs]))
-    replies = dw_tilde[idxs]
-    reply_nnz = jnp.sum(replies != 0, axis=1)
-    reply_sq = jnp.sum(replies * replies, axis=1)  # LAG's laziness reference
-    w_local = w_local.at[idxs].add(replies)
-    dw_tilde = dw_tilde.at[idxs].set(0.0)
+    with jax.named_scope(tracing.SERVER_APPLY):
+        total = jnp.zeros_like(w_server)
+        for p in payloads:
+            total = total + p
+        w_server = w_server + gamma * total
+        dw_tilde = dw_tilde + gamma * total[None, :]
+        snap = jnp.stack(list(snapshots))
+        mask = apply_mask[:, None]
+        alpha_applied = alpha_applied.at[idxs].set(
+            jnp.where(mask, snap, alpha_applied[idxs]))
+        replies = dw_tilde[idxs]
+        reply_nnz = jnp.sum(replies != 0, axis=1)
+        reply_sq = jnp.sum(replies * replies, axis=1)  # LAG's reference
+        w_local = w_local.at[idxs].add(replies)
+        dw_tilde = dw_tilde.at[idxs].set(0.0)
     return w_server, dw_tilde, w_local, alpha_applied, reply_nnz, reply_sq
 
 
@@ -383,10 +399,11 @@ def _lockstep_local_solves(w, alpha, X, y, norms_sq, lam, n, sigma_p, keys, *,
     (plain ``sum`` vs ``sum`` + ``psum``) differs between the two callers.
     """
     K = X.shape[0]
-    w_all = jnp.broadcast_to(w, (K, w.shape[0]))
     fn = partial(solver, loss=loss, num_steps=num_steps)
-    return jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None, None, None, 0))(
-        w_all, alpha, X, y, norms_sq, lam, n, sigma_p, keys)
+    with jax.named_scope(tracing.SOLVE):
+        w_all = jnp.broadcast_to(w, (K, w.shape[0]))
+        return jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None, None, None, 0))(
+            w_all, alpha, X, y, norms_sq, lam, n, sigma_p, keys)
 
 
 def _lockstep_round(key, w, alpha, X, y, norms_sq, lam, n, sigma_p, gamma, *,
@@ -405,8 +422,10 @@ def _lockstep_round(key, w, alpha, X, y, norms_sq, lam, n, sigma_p, gamma, *,
     dalpha, v = _lockstep_local_solves(w, alpha, X, y, norms_sq, lam, n,
                                        sigma_p, keys, loss=loss,
                                        num_steps=num_steps, solver=solver)
-    alpha = alpha + gamma * dalpha
-    w = w + gamma * jnp.sum(v, axis=0)
+    with jax.named_scope(tracing.SOLVE):
+        alpha = alpha + gamma * dalpha
+    with jax.named_scope(tracing.AGGREGATE):
+        w = w + gamma * jnp.sum(v, axis=0)
     return key, w, alpha
 
 
@@ -442,11 +461,12 @@ def _certificate_ops(w, alpha, X, y, lam, *, loss):
     reference's eager ``objectives.gap_certificate`` exactly (the bit-exact
     equivalence contract).
     """
-    w_alpha = objectives.primal_from_dual(alpha, X, lam)
-    p = objectives.primal_objective(w_alpha, X, y, lam, loss=loss)
-    dv = objectives.dual_objective(alpha, X, y, lam, loss=loss)
-    p_srv = objectives.primal_objective(w, X, y, lam, loss=loss)
-    return p, dv, p - dv, p_srv - dv
+    with jax.named_scope(tracing.CERTIFICATE):
+        w_alpha = objectives.primal_from_dual(alpha, X, lam)
+        p = objectives.primal_objective(w_alpha, X, y, lam, loss=loss)
+        dv = objectives.dual_objective(alpha, X, y, lam, loss=loss)
+        p_srv = objectives.primal_objective(w, X, y, lam, loss=loss)
+        return p, dv, p - dv, p_srv - dv
 
 
 @partial(jax.jit, static_argnames=("loss",))
@@ -735,31 +755,38 @@ class GroupProtocol(Protocol):
         m = self.method
         # Satellite of the vectorized-delay work: per-round vector draws
         # (ONE size-K numpy draw) for models that support them, per-message
-        # scalar draws (the legacy, reference-pinned order) otherwise.
-        durations = (self.delay.sample_round(m.H, self.rng)
-                     if self.delay.vector_sampled else None)
-        idxs = jnp.asarray([k for k, _ in starts], jnp.int32)
-        alpha_rows, sents, skips = self._round_payloads(idxs)
+        # scalar draws (the legacy, reference-pinned order: worker by
+        # worker in launch order) otherwise.
+        with tracing.span("repro.engine.delay_sample"):
+            if self.delay.vector_sampled:
+                drawn = self.delay.sample_round(m.H, self.rng)
+                durations = [drawn[k] for k, _ in starts]
+            else:
+                durations = [self.delay.compute_time(k, m.H, self.rng)
+                             for k, _ in starts]
+        with tracing.span("repro.engine.worker_dispatch"):
+            idxs = jnp.asarray([k for k, _ in starts], jnp.int32)
+            alpha_rows, sents, skips = self._round_payloads(idxs)
         out = []
-        for j, (k, start) in enumerate(starts):
-            if pre_account is not None:
-                rbytes, down_time = pre_account[j]
-                self.bytes_down += rbytes
-                self.comm_time += down_time
-            skipped = bool(skips[j]) if skips is not None else False
-            nbytes = self._message_bytes(skipped)
-            duration = (durations[k] if durations is not None
-                        else self.delay.compute_time(k, m.H, self.rng))
-            up_time = self.delay.p2p_time(nbytes, k)
-            self.compute_time += duration
-            self.comm_time += up_time
-            self.bytes_up += nbytes
-            self.seq += 1
-            msg = Message(start + duration + up_time, k, sents[j],
-                          alpha_rows[j], nbytes, self.seq,
-                          applied=not skipped)
-            self._observe_launch(k, start, msg.arrival)
-            out.append(msg)
+        with tracing.span("repro.engine.split"):
+            for j, (k, start) in enumerate(starts):
+                if pre_account is not None:
+                    rbytes, down_time = pre_account[j]
+                    self.bytes_down += rbytes
+                    self.comm_time += down_time
+                skipped = bool(skips[j]) if skips is not None else False
+                nbytes = self._message_bytes(skipped)
+                duration = durations[j]
+                up_time = self.delay.p2p_time(nbytes, k)
+                self.compute_time += duration
+                self.comm_time += up_time
+                self.bytes_up += nbytes
+                self.seq += 1
+                msg = Message(start + duration + up_time, k, sents[j],
+                              alpha_rows[j], nbytes, self.seq,
+                              applied=not skipped)
+                self._observe_launch(k, start, msg.arrival)
+                out.append(msg)
         return out
 
     def _observe_launch(self, k: int, start: float, arrival: float) -> None:
@@ -768,17 +795,19 @@ class GroupProtocol(Protocol):
     def _apply_server(self, arrived):
         """Fused aggregation + replies; returns (server_time, reply nnz)."""
         server_time = max(m.arrival for m in arrived)
-        idxs = jnp.asarray([m.worker for m in arrived], jnp.int32)
-        mask = jnp.asarray([m.applied for m in arrived], bool)
-        (self.w_server, self.dw_tilde, self.w_local, self.alpha_applied,
-         reply_nnz, reply_sq) = _server_apply_fused(
-            self.w_server, self.dw_tilde, self.w_local, self.alpha_applied,
-            idxs, tuple(m.payload for m in arrived),
-            tuple(m.alpha_snapshot for m in arrived), mask, self.method.gamma)
+        with tracing.span("repro.engine.server_dispatch"):
+            idxs = jnp.asarray([m.worker for m in arrived], jnp.int32)
+            mask = jnp.asarray([m.applied for m in arrived], bool)
+            (self.w_server, self.dw_tilde, self.w_local, self.alpha_applied,
+             reply_nnz, reply_sq) = _server_apply_fused(
+                self.w_server, self.dw_tilde, self.w_local,
+                self.alpha_applied, idxs, tuple(m.payload for m in arrived),
+                tuple(m.alpha_snapshot for m in arrived), mask,
+                self.method.gamma)
         self._last_reply_sq = reply_sq  # stays on device; LAG reads slices
         # The ONE host<->device sync of the round (skipped when replies are
         # dense, whose byte count is static).
-        nnz_host = None if self.dense else np.asarray(reply_nnz)
+        nnz_host = None if self.dense else _host_array(reply_nnz)
         return server_time, nnz_host
 
     def _reply_billing(self, j, worker, nnz_host) -> tuple[int, float]:
@@ -889,7 +918,7 @@ class LagProtocol(GroupProtocol):
             idxs, self.problem.lam, self.n, self.sigma_p, self.method.gamma,
             self.method.lag_xi, loss=self.problem.loss,
             num_steps=self.method.H, comp=self.comp)
-        return alpha_rows, sents, np.asarray(skips)  # one pull per group
+        return alpha_rows, sents, _host_array(skips)  # one pull per group
 
     def _message_bytes(self, skipped):
         return self.HEARTBEAT_BYTES if skipped else self.up_bytes
@@ -898,9 +927,10 @@ class LagProtocol(GroupProtocol):
         server_time, nnz_host = self._apply_server(arrived)
         # Slide this round's reply energies into the arrived workers'
         # windows (one fused dispatch, no host sync).
-        idxs = jnp.asarray([m.worker for m in arrived], jnp.int32)
-        self._ref_buf, self._ref_len = _lag_window_append(
-            self._ref_buf, self._ref_len, idxs, self._last_reply_sq)
+        with tracing.span("repro.engine.server_dispatch"):
+            idxs = jnp.asarray([m.worker for m in arrived], jnp.int32)
+            self._ref_buf, self._ref_len = _lag_window_append(
+                self._ref_buf, self._ref_len, idxs, self._last_reply_sq)
         starts, billing = [], []
         for j, m in enumerate(arrived):
             rbytes, down_time = self._reply_billing(j, m.worker, nnz_host)
@@ -968,10 +998,13 @@ class SyncProtocol(Protocol):
 
     def process_round(self, round_index, arrived):
         m = self.method
-        self._round_update()
+        with tracing.span("repro.engine.worker_dispatch"):
+            self._round_update()
         # One per-round vector draw (same host-RNG stream as K scalar calls
         # in worker order -- the order the pinned trajectories consumed).
-        step_compute = float(np.max(self.delay.sample_round(m.H, self.rng)))
+        with tracing.span("repro.engine.delay_sample"):
+            step_compute = float(np.max(self.delay.sample_round(m.H,
+                                                                self.rng)))
         step_comm = self.delay.allreduce_time(self.d)
         self.sim_time += step_compute + step_comm
         self.compute_time += step_compute
@@ -1261,17 +1294,19 @@ class PartialWorkProtocol(GroupProtocol):
             last = {}  # worker -> LAST harvested chunk's dual snapshot
             for msg in arrived:
                 last[msg.worker] = msg.alpha_snapshot
-            (self.w_server, self.dw_tilde, self.w_local, self.alpha_applied,
-             reply_nnz, reply_sq) = _server_apply_partial(
-                self.w_server, self.dw_tilde, self.w_local,
-                self.alpha_applied,
-                jnp.asarray(list(last.keys()), jnp.int32),
-                tuple(last.values()),
-                tuple(msg.payload for msg in arrived),
-                jnp.asarray(reply_to, jnp.int32), m.gamma)
+            with tracing.span("repro.engine.server_dispatch"):
+                (self.w_server, self.dw_tilde, self.w_local,
+                 self.alpha_applied, reply_nnz,
+                 reply_sq) = _server_apply_partial(
+                    self.w_server, self.dw_tilde, self.w_local,
+                    self.alpha_applied,
+                    jnp.asarray(list(last.keys()), jnp.int32),
+                    tuple(last.values()),
+                    tuple(msg.payload for msg in arrived),
+                    jnp.asarray(reply_to, jnp.int32), m.gamma)
             self._last_reply_sq = reply_sq
             if not self.dense and reply_to:
-                nnz_host = np.asarray(reply_nnz)
+                nnz_host = _host_array(reply_nnz)
         starts, billing = [], []
         for j, k in enumerate(reply_to):
             rbytes, down_time = self._reply_billing(j, k, nnz_host)
@@ -1315,13 +1350,15 @@ class PartialWorkProtocol(GroupProtocol):
             return []
         m = self.method
         C = len(self._chunk_steps)
-        if self.delay.vector_sampled:
-            sampled = self.delay.sample_chunks(self._chunk_steps, self.rng)
-            durations = [[sampled[c][k] for c in range(C)]
-                         for k, _ in starts]
-        else:
-            durations = [[self.delay.compute_time(k, h, self.rng)
-                          for h in self._chunk_steps] for k, _ in starts]
+        with tracing.span("repro.engine.delay_sample"):
+            if self.delay.vector_sampled:
+                sampled = self.delay.sample_chunks(self._chunk_steps,
+                                                   self.rng)
+                durations = [[sampled[c][k] for c in range(C)]
+                             for k, _ in starts]
+            else:
+                durations = [[self.delay.compute_time(k, h, self.rng)
+                              for h in self._chunk_steps] for k, _ in starts]
         finishes, n_sent = [], []
         for j, (k, start) in enumerate(starts):
             drop = self.cluster.next_drop_after(k, start)
@@ -1336,40 +1373,42 @@ class PartialWorkProtocol(GroupProtocol):
         # be materialized first (rare -- only drop-before-first-chunk).
         saved = {j: (self.alpha[k], self.residual[k])
                  for j, (k, _) in enumerate(starts) if n_sent[j] == 0}
-        idxs = jnp.asarray([k for k, _ in starts], jnp.int32)
-        (self.key, self.alpha, self.residual, alpha_rows, sents,
-         resids) = _worker_chunk_rounds_fused(
-            self.key, self.w_local, self.alpha, self.residual,
-            self.problem.X, self.problem.y, self.norms_sq, idxs,
-            self.problem.lam, self.n, self._live_sigma(), m.gamma,
-            loss=self.problem.loss, chunk_steps=self._chunk_steps,
-            comp=self.comp)
+        with tracing.span("repro.engine.worker_dispatch"):
+            idxs = jnp.asarray([k for k, _ in starts], jnp.int32)
+            (self.key, self.alpha, self.residual, alpha_rows, sents,
+             resids) = _worker_chunk_rounds_fused(
+                self.key, self.w_local, self.alpha, self.residual,
+                self.problem.X, self.problem.y, self.norms_sq, idxs,
+                self.problem.lam, self.n, self._live_sigma(), m.gamma,
+                loss=self.problem.loss, chunk_steps=self._chunk_steps,
+                comp=self.comp)
         out = []
-        for j, (k, start) in enumerate(starts):
-            if pre_account is not None:
-                rbytes, down_time = pre_account[j]
-                self.bytes_down += rbytes
-                self.comm_time += down_time
-            for c in range(n_sent[j]):
-                nbytes = self.up_bytes  # the one compressor formula, per chunk
-                up_time = self.delay.p2p_time(nbytes, k)
-                self.compute_time += durations[j][c]
-                self.comm_time += up_time
-                self.bytes_up += nbytes
-                self.seq += 1
-                msg = Message(finishes[j][c] + up_time, k, sents[j, c],
-                              alpha_rows[j, c], nbytes, self.seq,
-                              chunk=c, final=(c == C - 1))
-                self._pending[self.seq] = (msg.arrival, k, msg.final)
-                out.append(msg)
-            if n_sent[j] < C:
-                if n_sent[j] == 0:
-                    row_a, row_r = saved[j]
-                else:
-                    row_a = alpha_rows[j, n_sent[j] - 1]
-                    row_r = resids[j, n_sent[j] - 1]
-                self.alpha = self.alpha.at[k].set(row_a)
-                self.residual = self.residual.at[k].set(row_r)
+        with tracing.span("repro.engine.split"):
+            for j, (k, start) in enumerate(starts):
+                if pre_account is not None:
+                    rbytes, down_time = pre_account[j]
+                    self.bytes_down += rbytes
+                    self.comm_time += down_time
+                for c in range(n_sent[j]):
+                    nbytes = self.up_bytes  # the one compressor formula
+                    up_time = self.delay.p2p_time(nbytes, k)
+                    self.compute_time += durations[j][c]
+                    self.comm_time += up_time
+                    self.bytes_up += nbytes
+                    self.seq += 1
+                    msg = Message(finishes[j][c] + up_time, k, sents[j, c],
+                                  alpha_rows[j, c], nbytes, self.seq,
+                                  chunk=c, final=(c == C - 1))
+                    self._pending[self.seq] = (msg.arrival, k, msg.final)
+                    out.append(msg)
+                if n_sent[j] < C:
+                    if n_sent[j] == 0:
+                        row_a, row_r = saved[j]
+                    else:
+                        row_a = alpha_rows[j, n_sent[j] - 1]
+                        row_r = resids[j, n_sent[j] - 1]
+                    self.alpha = self.alpha.at[k].set(row_a)
+                    self.residual = self.residual.at[k].set(row_r)
         return out
 
 

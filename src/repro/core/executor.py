@@ -83,6 +83,7 @@ import numpy as np
 from repro.core import compress as compress_lib
 from repro.core import engine
 from repro.core import objectives
+from repro.core import tracing
 from repro.core.acpd import MethodConfig, RunResult
 from repro.core.simulate import ClusterModel
 
@@ -104,20 +105,11 @@ GAP_SCAN_AUTO_MAX_ROUNDS = 4096
 # Dispatch accounting for the 1-dispatch-per-run contract: "*_calls" counts
 # compiled executions (one per run), "*_traces" counts retraces (flat across
 # same-shape runs).  tests/test_executor.py + tests/test_sweep.py assert on
-# these.  The sweep counters live here (not in repro.api.sweep) so one reset
-# covers every scan-family entry point.
-STATS = {"lockstep_calls": 0, "lockstep_traces": 0,
-         "lockstep_gap_calls": 0, "lockstep_gap_traces": 0,
-         "lockstep_segment_calls": 0, "lockstep_segment_traces": 0,
-         "lag_calls": 0, "lag_traces": 0,
-         "partial_calls": 0, "partial_traces": 0,
-         "sweep_calls": 0, "sweep_traces": 0,
-         "sweep_lag_calls": 0, "sweep_lag_traces": 0}
-
-
-def reset_stats() -> None:
-    for k in STATS:
-        STATS[k] = 0
+# these.  The counters live in the leaf module repro.core.tracing (the event
+# engine counts its rounds there too, and it cannot import this module);
+# this is the same dict, so one reset covers every entry point.
+STATS = tracing.STATS
+reset_stats = tracing.reset_stats
 
 
 # ---------------------------------------------------------------------------
@@ -400,8 +392,10 @@ def lockstep_run_traced_sharded(key, X, y, norms_sq, lam, n, sigma_p, gamma,
         dalpha, v = engine._lockstep_local_solves(
             w, alpha, X, y, norms_sq, lam, n, sigma_p, local_keys, loss=loss,
             num_steps=num_steps, solver=solver)
-        alpha = alpha + gamma * dalpha
-        w = w + gamma * jax.lax.psum(jnp.sum(v, axis=0), axis)
+        with jax.named_scope(tracing.SOLVE):
+            alpha = alpha + gamma * dalpha
+        with jax.named_scope(tracing.AGGREGATE):
+            w = w + gamma * jax.lax.psum(jnp.sum(v, axis=0), axis)
         return (key, w, alpha), (w, alpha)
 
     (key, w, alpha), (ws, alphas) = jax.lax.scan(
@@ -706,55 +700,59 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
         s = dict(carry)
         need, dur_row = xs
         need = need.astype(i64)
-        # Pop order: lexicographic (arrival, seq) -- the host heap's order.
-        _, _, perm = jax.lax.sort((s["arrival"], s["seq"], iota), num_keys=2)
-        server_time = s["arrival"][perm][need - 1]
-        sel = iota < need
+        with jax.named_scope(tracing.SERVER_APPLY):
+            # Pop order: lexicographic (arrival, seq) -- the host heap's order.
+            _, _, perm = jax.lax.sort((s["arrival"], s["seq"], iota),
+                                      num_keys=2)
+            server_time = s["arrival"][perm][need - 1]
+            sel = iota < need
 
-        # Aggregation, summed in arrival order over exactly `need` payloads.
-        def agg(j, tot):
-            return tot + s["payload"][perm[j]]
+            # Aggregation, summed in arrival order over exactly `need`
+            # payloads.
+            def agg(j, tot):
+                return tot + s["payload"][perm[j]]
 
-        total = jax.lax.fori_loop(0, need, agg, jnp.zeros((d,), dt))
-        w_server = s["w_server"] + gamma * total
-        dw_tilde = s["dw_tilde"] + gamma * total[None, :]
+            total = jax.lax.fori_loop(0, need, agg, jnp.zeros((d,), dt))
+            w_server = s["w_server"] + gamma * total
+            dw_tilde = s["dw_tilde"] + gamma * total[None, :]
 
-        # Each arrived upload's dual snapshot (its worker's ``alpha`` row)
-        # becomes server-visible.  Selected per worker: XLA:TPU returned the
-        # operand unchanged for the rank-space form
-        # ``a.at[perm].set(where(m, b[perm], a[perm]))``.
-        _, rank = jax.lax.sort((perm, iota), num_keys=1)
-        mask = ((rank < need) & s["applied"])[:, None]
-        alpha_applied = jnp.where(mask, s["alpha"], s["alpha_applied"])
-        replies = dw_tilde[perm]
-        reply_nnz = jnp.sum(replies != 0, axis=1)
-        reply_sq = jnp.sum(replies * replies, axis=1)
-        w_rows = s["w_local"][perm]
-        w_local = s["w_local"].at[perm].set(
-            jnp.where(sel[:, None], w_rows + replies, w_rows))
-        dw_tilde = dw_tilde.at[perm].set(
-            jnp.where(sel[:, None], jnp.zeros_like(replies), dw_tilde[perm]))
+            # Each arrived upload's dual snapshot (its worker's ``alpha``
+            # row) becomes server-visible.  Selected per worker: XLA:TPU
+            # returned the operand unchanged for the rank-space form
+            # ``a.at[perm].set(where(m, b[perm], a[perm]))``.
+            _, rank = jax.lax.sort((perm, iota), num_keys=1)
+            mask = ((rank < need) & s["applied"])[:, None]
+            alpha_applied = jnp.where(mask, s["alpha"], s["alpha_applied"])
+            replies = dw_tilde[perm]
+            reply_nnz = jnp.sum(replies != 0, axis=1)
+            reply_sq = jnp.sum(replies * replies, axis=1)
+            w_rows = s["w_local"][perm]
+            w_local = s["w_local"].at[perm].set(
+                jnp.where(sel[:, None], w_rows + replies, w_rows))
+            dw_tilde = dw_tilde.at[perm].set(
+                jnp.where(sel[:, None], jnp.zeros_like(replies),
+                          dw_tilde[perm]))
 
-        # Reply-energy windows (the op sequence of _lag_window_append,
-        # masked to the arrived workers).
-        rows = s["ref_buf"][perm]
-        lens = s["ref_len"][perm]
-        full = (lens >= lag_window)[:, None]
-        shifted = jnp.where(full, jnp.roll(rows, -1, axis=1), rows)
-        pos = jnp.minimum(lens, lag_window - 1)
-        new_rows = shifted.at[jnp.arange(K), pos].set(reply_sq)
-        ref_buf = s["ref_buf"].at[perm].set(
-            jnp.where(sel[:, None], new_rows, rows))
-        ref_len = s["ref_len"].at[perm].set(
-            jnp.where(sel, jnp.minimum(lens + 1, lag_window), lens))
+            # Reply-energy windows (the op sequence of _lag_window_append,
+            # masked to the arrived workers).
+            rows = s["ref_buf"][perm]
+            lens = s["ref_len"][perm]
+            full = (lens >= lag_window)[:, None]
+            shifted = jnp.where(full, jnp.roll(rows, -1, axis=1), rows)
+            pos = jnp.minimum(lens, lag_window - 1)
+            new_rows = shifted.at[jnp.arange(K), pos].set(reply_sq)
+            ref_buf = s["ref_buf"].at[perm].set(
+                jnp.where(sel[:, None], new_rows, rows))
+            ref_len = s["ref_len"].at[perm].set(
+                jnp.where(sel, jnp.minimum(lens + 1, lag_window), lens))
 
-        # Reply billing per rank (same arithmetic as DelayModel.p2p_time).
-        if dense_reply_bytes:
-            reply_bytes = jnp.full((K,), dense_reply_bytes, i64)
-        else:
-            reply_bytes = (reply_nnz * 8).astype(i64)
-        down_times = latency + reply_bytes * link_factors[perm] / bandwidth
-        starts = server_time + down_times
+            # Reply billing per rank (same arithmetic as DelayModel.p2p_time).
+            if dense_reply_bytes:
+                reply_bytes = jnp.full((K,), dense_reply_bytes, i64)
+            else:
+                reply_bytes = (reply_nnz * 8).astype(i64)
+            down_times = latency + reply_bytes * link_factors[perm] / bandwidth
+            starts = server_time + down_times
 
         (key, alpha, residual, payload, applied, arrival, seq,
          seq_ctr), launch_bytes = launch(
@@ -1077,63 +1075,66 @@ def partial_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, durations,
         s = dict(carry)
         need, dur_wave = xs
         need = need.astype(i64)
-        # Deadline: the need-th FULL arrival, lex (arrival, seq) -- the host
-        # heap's order over final chunks (always in flight, see above).
-        arr_fin = s["arrival"][:, C - 1]
-        seq_fin = s["seq"][:, C - 1]
-        _, _, perm = jax.lax.sort((arr_fin, seq_fin, iota), num_keys=2)
-        sorted_arr = arr_fin[perm]
-        sorted_seq = seq_fin[perm]
-        server_time = sorted_arr[need - 1]
-        cut_s = sorted_seq[need - 1]
-        # Harvest: every pending chunk at or before the deadline key.
-        take = ~s["harvested"] & (
-            (s["arrival"] < server_time)
-            | ((s["arrival"] == server_time) & (s["seq"] <= cut_s)))
+        with jax.named_scope(tracing.SERVER_APPLY):
+            # Deadline: the need-th FULL arrival, lex (arrival, seq) -- the
+            # host heap's order over final chunks (always in flight, see
+            # above).
+            arr_fin = s["arrival"][:, C - 1]
+            seq_fin = s["seq"][:, C - 1]
+            _, _, perm = jax.lax.sort((arr_fin, seq_fin, iota), num_keys=2)
+            sorted_arr = arr_fin[perm]
+            sorted_seq = seq_fin[perm]
+            server_time = sorted_arr[need - 1]
+            cut_s = sorted_seq[need - 1]
+            # Harvest: every pending chunk at or before the deadline key.
+            take = ~s["harvested"] & (
+                (s["arrival"] < server_time)
+                | ((s["arrival"] == server_time) & (s["seq"] <= cut_s)))
 
-        # Aggregation in global arrival order over the harvested chunks:
-        # flattened lex sort, where-masked accumulation (event pop order).
-        _, _, fperm = jax.lax.sort(
-            (s["arrival"].reshape(KC), s["seq"].reshape(KC), kiota),
-            num_keys=2)
-        take_f = take.reshape(KC)
-        pay_f = s["payload"].reshape(KC, d)
+            # Aggregation in global arrival order over the harvested chunks:
+            # flattened lex sort, where-masked accumulation (event pop order).
+            _, _, fperm = jax.lax.sort(
+                (s["arrival"].reshape(KC), s["seq"].reshape(KC), kiota),
+                num_keys=2)
+            take_f = take.reshape(KC)
+            pay_f = s["payload"].reshape(KC, d)
 
-        def agg(j, tot):
-            p = fperm[j]
-            return jnp.where(take_f[p], tot + pay_f[p], tot)
+            def agg(j, tot):
+                p = fperm[j]
+                return jnp.where(take_f[p], tot + pay_f[p], tot)
 
-        total = jax.lax.fori_loop(0, KC, agg, jnp.zeros((d,), dt))
-        w_server = s["w_server"] + gamma * total
-        dw_tilde = s["dw_tilde"] + gamma * total[None, :]
+            total = jax.lax.fori_loop(0, KC, agg, jnp.zeros((d,), dt))
+            w_server = s["w_server"] + gamma * total
+            dw_tilde = s["dw_tilde"] + gamma * total[None, :]
 
-        # alpha_applied: each harvesting worker's LAST harvested chunk.
-        any_k = jnp.any(take, axis=1)
-        last = (C - 1) - jnp.argmax(take[:, ::-1], axis=1)
-        snap_last = s["snaps"][jnp.arange(K), last]
-        alpha_applied = jnp.where(any_k[:, None], snap_last,
-                                  s["alpha_applied"])
+            # alpha_applied: each harvesting worker's LAST harvested chunk.
+            any_k = jnp.any(take, axis=1)
+            last = (C - 1) - jnp.argmax(take[:, ::-1], axis=1)
+            snap_last = s["snaps"][jnp.arange(K), last]
+            alpha_applied = jnp.where(any_k[:, None], snap_last,
+                                      s["alpha_applied"])
 
-        # Catch-up replies to the `need` COMPLETED workers only (the event
-        # path's _server_apply_partial op order: replies read dw_tilde AFTER
-        # this round's harvest landed).
-        sel = iota < need
-        replies = dw_tilde[perm]
-        reply_nnz = jnp.sum(replies != 0, axis=1)
-        w_rows = s["w_local"][perm]
-        w_local = s["w_local"].at[perm].set(
-            jnp.where(sel[:, None], w_rows + replies, w_rows))
-        dw_tilde = dw_tilde.at[perm].set(
-            jnp.where(sel[:, None], jnp.zeros_like(replies), dw_tilde[perm]))
+            # Catch-up replies to the `need` COMPLETED workers only (the event
+            # path's _server_apply_partial op order: replies read dw_tilde
+            # AFTER this round's harvest landed).
+            sel = iota < need
+            replies = dw_tilde[perm]
+            reply_nnz = jnp.sum(replies != 0, axis=1)
+            w_rows = s["w_local"][perm]
+            w_local = s["w_local"].at[perm].set(
+                jnp.where(sel[:, None], w_rows + replies, w_rows))
+            dw_tilde = dw_tilde.at[perm].set(
+                jnp.where(sel[:, None], jnp.zeros_like(replies),
+                          dw_tilde[perm]))
 
-        # Reply billing per rank (same arithmetic as DelayModel.p2p_time).
-        if dense_reply_bytes:
-            reply_bytes = jnp.full((K,), dense_reply_bytes, i64)
-        else:
-            reply_bytes = (reply_nnz * 8).astype(i64)
-        factors = link_factors[perm]
-        down_times = latency + reply_bytes * factors / bandwidth
-        starts = server_time + down_times
+            # Reply billing per rank (same arithmetic as DelayModel.p2p_time).
+            if dense_reply_bytes:
+                reply_bytes = jnp.full((K,), dense_reply_bytes, i64)
+            else:
+                reply_bytes = (reply_nnz * 8).astype(i64)
+            factors = link_factors[perm]
+            down_times = latency + reply_bytes * factors / bandwidth
+            starts = server_time + down_times
 
         harvested = s["harvested"] | take
         (key, alpha, residual, payload, snaps, arrival, seq, harvested,

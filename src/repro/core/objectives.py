@@ -31,6 +31,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
+
 LossName = Literal["ridge", "smoothed_hinge", "logistic"]
 
 # Full f32 products: a TPU runs a DEFAULT-precision f32 dot in one bf16
@@ -205,13 +207,11 @@ def gap_certificate(problem: Problem, alpha: jax.Array, w: jax.Array | None = No
     w_alpha = primal_from_dual(alpha, X, lam)
     p = primal_objective(w_alpha, X, y, lam, loss=loss)
     dv = dual_objective(alpha, X, y, lam, loss=loss)
-    out = {
-        "primal": float(p),
-        "dual": float(dv),
-        "gap": float(p - dv),
-    }
+    values = {"primal": p, "dual": dv, "gap": p - dv}
     if w is not None:
         p_srv = primal_objective(w, X, y, lam, loss=loss)
-        out["primal_server"] = float(p_srv)
-        out["gap_server"] = float(p_srv - dv)
-    return out
+        values["primal_server"] = p_srv
+        values["gap_server"] = p_srv - dv
+    # Every dispatch is queued before the first blocking read.
+    with tracing.span("repro.certificate.sync"):
+        return {k: tracing.host_read(v) for k, v in values.items()}

@@ -44,6 +44,7 @@ def lint_fixture(name: str, rule: str) -> list[Finding]:
 @pytest.mark.parametrize("fixture,rule", [
     ("bad_mesh.py", "mesh-via-make-mesh"),
     ("bad_host_sync.py", "traced-host-sync"),
+    ("bad_traced_span.py", "traced-span"),
     ("bad_donation.py", "jit-donation"),
     ("bad_f64.py", "f64-without-x64"),
     ("bad_registry.py", "registry-hooks"),
@@ -96,8 +97,9 @@ def test_finding_format_is_clickable():
 
 def test_rule_registry():
     rules = lint.available_rules()
-    for name in ("mesh-via-make-mesh", "traced-host-sync", "jit-donation",
-                 "f64-without-x64", "registry-hooks", "typed-errors"):
+    for name in ("mesh-via-make-mesh", "traced-host-sync", "traced-span",
+                 "jit-donation", "f64-without-x64", "registry-hooks",
+                 "typed-errors"):
         assert name in rules
         assert lint.get_rule(name).description
     with pytest.raises(ValueError, match="unknown analysis rule"):
