@@ -5,6 +5,10 @@ Each phase runs one user path of ``repro.api`` on the chip, then the same
 spec on the host's CPU backend in the same process, and checks that the two
 agree:
 
+  k. kernel     -- one worker's local pass on the SDCA kernel against the
+                   jnp path over the same visit order, both on the chip, in
+                   both tilings of X (by worker, and by row with a last
+                   tile of 5 rows)
   a. group      -- the paper's Algorithms 1+2 on the event engine: the server
                    applies the first B=8 of K=16 replies, with a full barrier
                    every T=10 rounds, under pareto stragglers and the paper's
@@ -13,7 +17,8 @@ agree:
   c. lag        -- the scan with the B-of-K event queue in the graph: float64
                    arrival times order the replies on the device, and the
                    executor replays the clocks on the host and checks the
-                   device's pop order against them every round
+                   device's pop order against them every round; then
+                   the same spec on the event engine, both on the chip
   d. run_sweep  -- one compiled seed x gamma grid of cocoa_plus, sized from its
                    own memory analysis to fit the chip
   e. service    -- an in-process ExperimentService coalescing two tenants'
@@ -26,7 +31,7 @@ The problem has the shape of LIBSVM's rcv1.binary: d = 47,236 features and
 (H = 1,265).
 
 Usage:
-  python chip_smoke.py                 # one chip: phases a-e
+  python chip_smoke.py                 # one chip: phases k, a-e
   python chip_smoke.py --four-chips    # run_sweep sharded over four chips
                                        # (cells, workers) vs one chip
 
@@ -230,6 +235,63 @@ def run_session(api, problem, spec):
     return events, session.result()
 
 
+def kernel_pass_phase(smoke, problem, reps: int = 5) -> dict:
+    """One worker's pass of H steps on the SDCA kernel against the jnp path
+    (:func:`repro.core.sdca.solve_subproblem_indices` on ``X[k]``), both on
+    the chip, from the same w_eff, duals and visit order: worker 5 of the
+    rcv1-width X (tiled by worker), then worker 2 of its first 3 workers'
+    first 1,261 rows (tiled by row, so the last 8-row tile holds 5 rows;
+    the pass starts on them)."""
+    jax, jnp = smoke.jax, smoke.jax.numpy
+    from repro.core.sdca import solve_subproblem_indices, visit_order
+    from repro.kernels import sdca_inner
+
+    check(sdca_inner.applies(smoke.tpu.platform, problem.loss, N_K, D, H),
+          "the kernel does not apply at this width")
+    lam, n, sigma_p = problem.lam, K * N_K, float(B)
+    k1, k2, k3 = jax.random.split(jax.random.key(smoke.seed), 3)
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    def one(X, y, k, idx):
+        n_k, d = X.shape[1:]
+        w_eff = 1e-2 * jax.random.normal(k1, (d,), X.dtype)
+        alpha = 1e-2 * jax.random.normal(k2, (n_k,), X.dtype)
+        norms = jnp.sum(X[k] * X[k], axis=-1)
+        kernel = jax.jit(lambda X: sdca_inner.sdca_pass(
+            w_eff, alpha, X, k, y[k], norms, lam, n, sigma_p, idx,
+            loss=problem.loss))
+        xla = jax.jit(lambda X: solve_subproblem_indices(
+            w_eff, alpha, X[k], y[k], norms, lam, n, sigma_p, idx,
+            loss=problem.loss))
+
+        def seconds(fn):
+            out = jax.block_until_ready(fn(X))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                jax.block_until_ready(fn(X))
+            return out, (time.perf_counter() - t0) / reps
+
+        (da, v), kernel_s = seconds(kernel)
+        (da_ref, v_ref), xla_s = seconds(xla)
+        out = dict(rel_dalpha=rel(da, da_ref), rel_v=rel(v, v_ref),
+                   tiled_by_worker=sdca_inner._tiled_by_worker(*X.shape[:2]),
+                   n_k=n_k, kernel_us_per_step=1e6 * kernel_s / idx.size,
+                   xla_us_per_step=1e6 * xla_s / idx.size)
+        check(out["rel_dalpha"] <= RTOL and out["rel_v"] <= RTOL,
+              f"kernel pass vs jnp path, n_k={n_k}: rel. error dalpha "
+              f"{out['rel_dalpha']!r}, v {out['rel_v']!r} (rtol {RTOL})")
+        return out
+
+    rcv1 = one(problem.X, problem.y, 5, visit_order(k3, N_K, H))
+    n_k = N_K - 4
+    tail = jnp.arange(n_k // 8 * 8, n_k, dtype=jnp.int32)
+    rows = one(problem.X[:3, :n_k], problem.y[:3, :n_k], 2, jnp.concatenate(
+        [tail, visit_order(k3, n_k, H - tail.size)]))
+    check(rcv1["tiled_by_worker"] and not rows["tiled_by_worker"],
+          "the two passes do not cover both tilings")
+    return dict(steps=H, rcv1=rcv1, row_tiles=rows)
+
+
 def one_chip(smoke) -> None:
     from repro import api
     from repro.core import objectives
@@ -244,7 +306,7 @@ def one_chip(smoke) -> None:
     gap0 = objectives.gap_certificate(problem, zeros)["gap"]
     smoke.emit(phase="initial", gap=gap0)
     phase_specs = specs(api, problem_spec, smoke.seed)
-    cpu_runs = {}
+    cpu_runs, chip_runs = {}, {}
 
     def session_phase(name):
         spec = phase_specs[name]
@@ -253,6 +315,7 @@ def one_chip(smoke) -> None:
         with jax.default_device(smoke.cpu):
             cpu_events, cpu_result = run_session(api, cpu_problem, spec)
         cpu_runs[name] = (cpu_events, cpu_result)
+        chip_runs[name] = (events, result)
         compare_streams(events, cpu_events, name)
         compare_records(result.records, cpu_result.records, name)
         gap = result.records[-1].gap
@@ -262,9 +325,25 @@ def one_chip(smoke) -> None:
                     rounds=len(round_stream(events)), gap_chip=gap,
                     gap_cpu=cpu_result.records[-1].gap)
 
+    smoke.phase("k-kernel_pass", lambda: kernel_pass_phase(smoke, problem))
     smoke.phase("a-group", lambda: session_phase("group"))
     smoke.phase("b-cocoa_plus", lambda: session_phase("cocoa_plus"))
     smoke.phase("c-lag", lambda: session_phase("lag"))
+
+    def lag_event_phase():
+        """The lag scan against the event engine, both on the chip."""
+        spec = dataclasses.replace(phase_specs["lag"], executor="event")
+        scan_events, scan_result = chip_runs["lag"]
+        (events, result), compile_s, run_s = smoke.timed(
+            lambda: run_session(api, problem, spec))
+        compare_streams(events, scan_events, "lag event vs scan")
+        compare_records(result.records, scan_result.records,
+                        "lag event vs scan")
+        return dict(compile_s=compile_s, run_s=run_s,
+                    gap_event=result.records[-1].gap,
+                    gap_scan=scan_result.records[-1].gap)
+
+    smoke.phase("c2-lag_event", lag_event_phase)
 
     base = phase_specs["cocoa_plus"]
     method, cluster = base.methods[0].config, base.cluster

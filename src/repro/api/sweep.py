@@ -290,14 +290,14 @@ def _lag_sweep_scan(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, xi,
     """
     executor.STATS["sweep_lag_traces"] += 1  # trace-time side effect
 
-    def one(shared, key, sp, g, dur, lat, bw, lf):
+    def one(shared, cells, key, sp, g, dur, lat, bw, lf):
         (X, y, norms_sq, lam, n, xi, needs, up_bytes, heartbeat_bytes,
          idx) = shared
         state, ys = executor.lag_run_traced(
             key, X, y, norms_sq, lam, n, sp, g, xi, dur, needs, up_bytes,
             heartbeat_bytes, lat, bw, lf, loss=loss, num_steps=num_steps,
             comp=comp, length=length, lag_window=lag_window,
-            dense_reply_bytes=dense_reply_bytes)
+            dense_reply_bytes=dense_reply_bytes, cells=cells)
         ws, app_rows, order, reply_bytes, launch_bytes = ys
         return (state["w_server"], state["alpha"], state["alpha_applied"],
                 ws[idx], app_rows[idx], state["init_bytes"], order,
@@ -308,11 +308,11 @@ def _lag_sweep_scan(keys, X, y, norms_sq, lam, n, sigma_ps, gammas, xi,
               link_factors, idx):
         shared = (X, y, norms_sq, lam, n, xi, needs, up_bytes,
                   heartbeat_bytes, idx)
-        if batch == "vmap":
-            return jax.vmap(partial(one, shared))(
+        if batch == "vmap":  # the cells of this block, one vectorised run
+            return jax.vmap(partial(one, shared, keys.shape[0]))(
                 keys, sigma_ps, gammas, durations, latencies, bandwidths,
                 link_factors)
-        return jax.lax.map(lambda a: one(shared, *a),
+        return jax.lax.map(lambda a: one(shared, 1, *a),
                            (keys, sigma_ps, gammas, durations, latencies,
                             bandwidths, link_factors))
 

@@ -77,8 +77,9 @@ from repro.core import filter as msg_filter
 from repro.core import objectives
 from repro.core import tracing
 from repro.core.acpd import MethodConfig, RunRecord, RunResult
-from repro.core.sdca import solve_subproblem
+from repro.core.sdca import solve_subproblem, visit_order
 from repro.core.simulate import ClusterModel
+from repro.kernels import sdca_inner
 
 # ---------------------------------------------------------------------------
 # Protocol registry.
@@ -166,18 +167,40 @@ class _Snapshot:
 # ---------------------------------------------------------------------------
 
 
-def _local_round(key, w_local, alpha_k, residual_k, X_k, y_k, norms_k, k, lam,
-                 n, sigma_p, gamma, *, loss, num_steps, comp):
+def _local_round(key, w_local, alpha_k, residual_k, X, y_k, norms_k, k, lam,
+                 n, sigma_p, gamma, *, loss, num_steps, comp, cells=1):
     """Shared Alg. 2 body: solve + dual update + filter. Traced, not jitted --
     both fused worker rounds inline it so the op sequence (and therefore the
-    bit-exact trajectory) is defined in exactly one place. ``comp`` is a
-    frozen :mod:`repro.core.compress` registry object (static under jit)."""
+    bit-exact trajectory) is defined in exactly one place. ``X`` is the
+    stacked (K, n_k, d) data and ``k`` the worker.  ``comp`` is a frozen
+    :mod:`repro.core.compress` registry object (static under jit).
+
+    The local pass runs on the SDCA kernel where
+    :func:`repro.kernels.sdca_inner.applies` (a TPU, a closed-form loss, the
+    shape within the kernel's budgets, and ``cells``, the number of runs
+    the caller vmaps this round over, is 1), reading worker ``k``'s rows
+    out of ``X`` in place; elsewhere it runs :func:`solve_subproblem` on the
+    copy ``X[k]``.  Both draw the same visit order."""
+    _, n_k, d = X.shape
+    on_kernel = sdca_inner.fits(loss, n_k, d, num_steps, cells)
+    X_k = None if on_kernel else X[k]  # copied under the caller's scope
     with jax.named_scope(tracing.SOLVE):
         key, sub = jax.random.split(key)
         w_eff = w_local[k] + gamma * residual_k
-        dalpha, v = solve_subproblem(
-            w_eff, alpha_k, X_k, y_k, norms_k, lam, n, sigma_p, sub,
-            loss=loss, num_steps=num_steps)
+
+        def jnp_pass(X_k):
+            return solve_subproblem(
+                w_eff, alpha_k, X_k, y_k, norms_k, lam, n, sigma_p, sub,
+                loss=loss, num_steps=num_steps)
+
+        if on_kernel:
+            dalpha, v = jax.lax.platform_dependent(
+                tpu=lambda: sdca_inner.sdca_pass(
+                    w_eff, alpha_k, X, k, y_k, norms_k, lam, n, sigma_p,
+                    visit_order(sub, n_k, num_steps), loss=loss),
+                default=lambda: jnp_pass(X[k]))
+        else:
+            dalpha, v = jnp_pass(X_k)
         alpha_new = alpha_k + gamma * dalpha  # Alg. 2 line 5
         dw = residual_k + v  # line 6
     with jax.named_scope(tracing.FILTER):
@@ -204,7 +227,7 @@ def _worker_rounds_fused(key, w_local, alpha, residual, X, y, norms_sq, idxs,
     def body(carry, k):
         key, alpha, residual = carry
         key, alpha_k, res_k, _, sent = _local_round(
-            key, w_local, alpha[k], residual[k], X[k], y[k], norms_sq[k], k,
+            key, w_local, alpha[k], residual[k], X, y[k], norms_sq[k], k,
             lam, n, sigma_p, gamma, loss=loss, num_steps=num_steps, comp=comp)
         carry = (key, alpha.at[k].set(alpha_k), residual.at[k].set(res_k))
         return carry, (alpha_k, sent)
@@ -240,7 +263,7 @@ def _worker_chunk_rounds_fused(key, w_local, alpha, residual, X, y, norms_sq,
         snaps, sents, resids = [], [], []
         for h in chunk_steps:
             key, alpha_k, res_k, _, sent = _local_round(
-                key, w_local, alpha_k, res_k, X[k], y[k], norms_sq[k], k,
+                key, w_local, alpha_k, res_k, X, y[k], norms_sq[k], k,
                 lam, n, sigma_p, gamma, loss=loss, num_steps=h, comp=comp)
             snaps.append(alpha_k)
             sents.append(sent)
@@ -341,7 +364,7 @@ def _worker_rounds_lag_fused(key, w_local, alpha, residual, ref_buf, ref_len,
         key, alpha, residual = carry
         ref_k = _lag_reference(ref_buf[k], ref_len[k], xi)
         key, alpha_k, res_k, dw, sent = _local_round(
-            key, w_local, alpha[k], residual[k], X[k], y[k], norms_sq[k], k,
+            key, w_local, alpha[k], residual[k], X, y[k], norms_sq[k], k,
             lam, n, sigma_p, gamma, loss=loss, num_steps=num_steps, comp=comp)
         send_sq = jnp.vdot(sent, sent, precision=objectives.HIGHEST)
         skip = send_sq < ref_k
@@ -636,6 +659,9 @@ class Protocol:
         self.delay = cluster.make_delay()  # fresh per run; may be stateful
         self.K, self.n_k, self.d = problem.X.shape
         self.n = self.K * self.n_k
+        self.platform = (next(iter(problem.X.devices())).platform
+                         if isinstance(problem.X, jax.Array)
+                         else jax.default_backend())
         self.sigma_p = method.resolved_sigma_prime(self.K)
         self.rng = np.random.default_rng(seed)
         self.key = jax.random.key(seed)
@@ -645,6 +671,15 @@ class Protocol:
         self.comm_time = 0.0
         self.sim_time = 0.0
         self.seq = 0
+
+    def _count_solves(self, workers: int, steps: Iterable[int]) -> None:
+        """Counts a worker program's local passes in ``tracing.STATS``:
+        ``workers`` workers each run one pass of every length in ``steps``."""
+        for h in steps:
+            tracing.STATS["solve_passes"] += workers
+            if sdca_inner.applies(self.platform, self.problem.loss, self.n_k,
+                                  self.d, h):
+                tracing.STATS["solve_kernel_passes"] += workers
 
     # --- hooks the engine loop calls (contract in the class docstring) ----
 
@@ -767,6 +802,7 @@ class GroupProtocol(Protocol):
         with tracing.span("repro.engine.worker_dispatch"):
             idxs = jnp.asarray([k for k, _ in starts], jnp.int32)
             alpha_rows, sents, skips = self._round_payloads(idxs)
+            self._count_solves(len(starts), (m.H,))
         out = []
         with tracing.span("repro.engine.split"):
             for j, (k, start) in enumerate(starts):
@@ -1382,6 +1418,7 @@ class PartialWorkProtocol(GroupProtocol):
                 self.problem.lam, self.n, self._live_sigma(), m.gamma,
                 loss=self.problem.loss, chunk_steps=self._chunk_steps,
                 comp=self.comp)
+            self._count_solves(len(starts), self._chunk_steps)
         out = []
         with tracing.span("repro.engine.split"):
             for j, (k, start) in enumerate(starts):

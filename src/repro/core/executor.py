@@ -594,7 +594,7 @@ def _run_lockstep_gap(problem, method, rounds, sigma_p, *, num_outer, seed,
 def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
                    needs, up_bytes, heartbeat_bytes, latency,
                    bandwidth, link_factors, *, loss, num_steps, comp, length,
-                   lag_window, dense_reply_bytes):
+                   lag_window, dense_reply_bytes, cells=1):
     """The whole LAG run as a traced computation: in-graph B-of-K event queue.
 
     Carries per-worker in-flight message state (payload, arrival time f64,
@@ -638,9 +638,9 @@ def lag_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, xi, durations,
             j, k, start = xs
             ref_k = engine._lag_reference(ref_buf[k], ref_len[k], xi)
             key, alpha_k, res_k, dw, sent = engine._local_round(
-                key, w_local, alpha[k], residual[k], X[k], y[k], norms_sq[k],
+                key, w_local, alpha[k], residual[k], X, y[k], norms_sq[k],
                 k, lam, n, sigma_p, gamma, loss=loss, num_steps=num_steps,
-                comp=comp)
+                comp=comp, cells=cells)
             send_sq = jnp.vdot(sent, sent, precision=objectives.HIGHEST)
             skip = send_sq < ref_k
             sent = jnp.where(skip, jnp.zeros_like(sent), sent)
@@ -990,7 +990,7 @@ def partial_run_traced(key, X, y, norms_sq, lam, n, sigma_p, gamma, durations,
             pays, snps, arrs, seqs = [], [], [], []
             for c, h in enumerate(chunk_steps):
                 key, alpha_k, res_k, _, sent = engine._local_round(
-                    key, w_local, alpha_k, res_k, X[k], y[k], norms_sq[k],
+                    key, w_local, alpha_k, res_k, X, y[k], norms_sq[k],
                     k, lam, n, sigma_p, gamma, loss=loss, num_steps=h,
                     comp=comp)
                 dur = dur_wave[c, k]

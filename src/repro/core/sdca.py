@@ -103,6 +103,14 @@ def solve_subproblem_indices(
     return LocalSolveResult(dalpha, v)
 
 
+def visit_order(key: jax.Array, n_k: int, num_steps: int) -> jax.Array:
+    """A local pass's ``num_steps`` coordinates, uniform with replacement."""
+    # Explicit dtype: the default follows the x64 flag, and the scan-fused
+    # executor traces this under enable_x64 -- int64 draws would consume the
+    # PRNG differently and break executor bit-equivalence.
+    return jax.random.randint(key, (num_steps,), 0, n_k, dtype=jnp.int32)
+
+
 @partial(jax.jit, static_argnames=("loss", "num_steps"))
 def solve_subproblem(
     w_eff: jax.Array,
@@ -119,11 +127,7 @@ def solve_subproblem(
     num_steps: int,
 ) -> LocalSolveResult:
     """H sequential SDCA steps with uniform sampling (Alg. 2 line 4)."""
-    n_k = X.shape[0]
-    # Explicit dtype: the default follows the x64 flag, and the scan-fused
-    # executor traces this under enable_x64 -- int64 draws would consume the
-    # PRNG differently and break executor bit-equivalence.
-    idx = jax.random.randint(key, (num_steps,), 0, n_k, dtype=jnp.int32)
+    idx = visit_order(key, X.shape[0], num_steps)
     return solve_subproblem_indices(
         w_eff, alpha, X, y, norms_sq, lam, n_global, sigma_prime, idx, loss=loss)
 

@@ -9,7 +9,11 @@ Everything here lands in one place a profiler can read, on one clock:
   ``event_arrivals`` (messages popped off the arrival queue) and
   ``host_syncs`` (blocking device->host reads inside rounds and streamed
   certificates: a round's reply ``nnz``, LAG's skip flags, each ``float``
-  of a gap certificate).  ``repro.core.executor.STATS`` is this dict.
+  of a gap certificate), and the local passes of the engine's fused worker
+  programs: ``solve_passes`` (every worker pass dispatched) and
+  ``solve_kernel_passes`` (those that run on the SDCA kernel,
+  :func:`repro.kernels.sdca_inner.applies`).  The scan executors' passes
+  are not counted.  ``repro.core.executor.STATS`` is this dict.
 * :func:`span` -- a host span in the JAX profiler's trace
   (``jax.profiler.TraceAnnotation``), named ``repro.<layer>.<what>``.  With
   no profiler running it costs about a microsecond and records nothing;
@@ -48,7 +52,8 @@ STATS = {"lockstep_calls": 0, "lockstep_traces": 0,
          "partial_calls": 0, "partial_traces": 0,
          "sweep_calls": 0, "sweep_traces": 0,
          "sweep_lag_calls": 0, "sweep_lag_traces": 0,
-         "event_rounds": 0, "event_arrivals": 0, "host_syncs": 0}
+         "event_rounds": 0, "event_arrivals": 0, "host_syncs": 0,
+         "solve_passes": 0, "solve_kernel_passes": 0}
 
 
 def reset_stats() -> None:

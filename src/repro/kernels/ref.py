@@ -20,9 +20,10 @@ def topk_filter_ref(dw: jax.Array, k: int):
     return sent, dw - sent, mask
 
 
-def sdca_inner_ref(w_eff, alpha, X, y, norms_sq, lam, n_global, sigma_prime, idx):
-    """vmapped-over-workers ridge SDCA epoch with explicit visit order."""
-    fn = functools.partial(solve_subproblem_indices, loss="ridge")
+def sdca_inner_ref(w_eff, alpha, X, y, norms_sq, lam, n_global, sigma_prime, idx,
+                   *, loss="ridge"):
+    """vmapped-over-workers SDCA epoch with explicit visit order."""
+    fn = functools.partial(solve_subproblem_indices, loss=loss)
     dalpha, v = jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None, None, None, 0))(
         w_eff, alpha, X, y, norms_sq, lam, n_global, sigma_prime, idx)
     return dalpha, v

@@ -2,6 +2,7 @@
 event loop's boundaries, the host spans a profiler records, the device
 scopes in the compiled programs, and that none of it changes a result."""
 
+import dataclasses
 import re
 
 import jax
@@ -51,6 +52,37 @@ def test_event_loop_counters(small_problem, eval_mode, syncs_per_eval):
     assert delta["event_arrivals"] == OUTER * ((T - 1) * B + K)
     # One reply-nnz read a round (sparse replies), plus the certificates'.
     assert delta["host_syncs"] == rounds + syncs_per_eval * evals
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("n_chunks", [1, 2])
+def test_solve_pass_counters(small_problem, monkeypatch, on_tpu, n_chunks):
+    """Every worker pass the engine dispatches counts once in
+    ``solve_passes``, and in ``solve_kernel_passes`` too where the kernel
+    applies: nowhere on the CPU, everywhere for this ridge problem on a
+    TPU (the protocol's platform stood in for here)."""
+    from repro.kernels import sdca_inner
+
+    if on_tpu:
+        applies = sdca_inner.applies
+        monkeypatch.setattr(sdca_inner, "applies",
+                            lambda _platform, *rest: applies("tpu", *rest))
+    method = _group()
+    if n_chunks > 1:
+        method = dataclasses.replace(method, protocol="partial_work",
+                                     n_chunks=n_chunks)
+    before = dict(tracing.STATS)
+    session = api.Session(small_problem, method, _cluster(),
+                          num_outer=OUTER, seed=3, eval_every=EVAL_EVERY,
+                          executor="event")
+    session.run()
+    delta = {k: tracing.STATS[k] - before[k] for k in tracing.STATS}
+    # The t=0 wave launches all K workers; each pass that arrives (all of
+    # its chunks: no worker drops out here) relaunches its worker.
+    launches = K + delta["event_arrivals"] // n_chunks
+    assert delta["solve_passes"] == n_chunks * launches
+    assert delta["solve_kernel_passes"] == (delta["solve_passes"] if on_tpu
+                                            else 0)
 
 
 def _results_and_sweep(problem):
